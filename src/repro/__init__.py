@@ -1,9 +1,1 @@
-"""repro — Decomposing Collectives for Exploiting Multi-lane Communication.
-
-Importing any submodule installs the JAX version-compat shims first (see
-repro.compat): the code targets the modern jax.shard_map / lax.pcast
-surface but must also run on pinned 0.4.x containers.
-"""
-from . import compat as _compat
-
-_compat.install()
+"""repro — Decomposing Collectives for Exploiting Multi-lane Communication."""

@@ -47,7 +47,6 @@ A1_ALLOWED_DIRS = ("comm", "core", "testing")
 
 #: file → why it may touch raw collectives / shard_map
 A1_FILE_WHITELIST = {
-    "compat.py": "jax version shim: re-exports shard_map itself",
     "launch/steps.py": "step assembly: shard_map wrapping and the "
                        "scalar loss/grad-norm reductions of the step "
                        "skeleton (payload comm goes through LaneComm)",

@@ -2,7 +2,7 @@
 concurrency, and the per-level communication footprint behind lanelint.
 
 This module is the home of what used to live in ``launch/hlo_stats.py``
-(which now re-exports from here for back-compat):
+(which now re-exports from here for older importers):
 
   * exact-ish HLO accounting — dot FLOPs, HBM-traffic bytes, collective
     bytes, with while-loop bodies multiplied by their known trip counts

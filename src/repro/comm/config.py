@@ -70,7 +70,7 @@ class CommConfig:
 
     @classmethod
     def from_run(cls, run: "RunConfig") -> "CommConfig":
-        """Bridge from the legacy RunConfig knobs (kept for back-compat)."""
+        """Bridge from the legacy RunConfig knobs."""
         return cls(
             strategy=run.gradsync,
             buckets=run.gradsync_buckets,

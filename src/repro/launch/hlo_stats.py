@@ -1,4 +1,4 @@
-"""Back-compat shim: the HLO parse/accounting core moved to
+"""Re-export shim: the HLO parse/accounting core moved to
 ``repro.analysis.footprint`` (the lanelint static-analysis subsystem
 generalized it into the shared footprint layer, DESIGN.md §12).
 

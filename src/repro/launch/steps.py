@@ -153,8 +153,8 @@ def build_train_step_lane(cfg: ModelConfig, run: RunConfig, opt: AdamWConfig,
     payload and records the choice on the returned comm's ``selections``.
     On a single-batch-axis mesh the node level is trivial and every
     replicated flavor degrades to the native one-shot psum.
-    ``param_specs`` is accepted for call-site compatibility but unused:
-    the caller owns the shard_map in/out specs of the returned step.
+    ``param_specs`` is accepted so existing call sites keep working,
+    but unused: the caller owns the shard_map in/out specs of the returned step.
     ``tuner`` (a ``repro.tuning.Tuner`` or None) lands on the comm's
     ``CommConfig.tuner``: measured timing-cache costs then outrank the
     closed-form model in every auto dispatch this step makes.

@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                           + os.environ.get("XLA_FLAGS", ""))
-# The two lines above MUST run before any jax import (device count locks
-# at first backend init) — this module is a standalone CI entry point.
 """CI leg: the THIRD parallelism axis through the real training driver.
 
 Sweeps ``--model-parallel`` (tensor parallelism over the mesh's 'model'
@@ -21,8 +16,8 @@ this leg certifies the DRIVER surface end to end.
 
 Usage:  python -m repro.launch.tp_smoke   (wired into ``make ci``)
 """
-import sys                                                    # noqa: E402
-import tempfile                                               # noqa: E402
+import sys
+import tempfile
 
 
 # (name, arch, gradsync, extra args) — TP over dense for both replicated
@@ -75,4 +70,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # before the first jax import (main imports it): 8 CPU devices
+    from repro.tuning.backend import apply_backend_setup
+    apply_backend_setup("cpu", host_device_count=8)
     sys.exit(main(sys.argv[1:]))

@@ -61,7 +61,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import resolve, RunConfig
 from repro.configs.base import ShapeConfig
@@ -70,7 +70,9 @@ from repro.optim import AdamWConfig
 from repro.checkpoint import AsyncCheckpointer, latest_step
 from repro.data import make_loader
 from repro.launch.mesh import batch_axes
-from repro.launch.steps import (build_train_step_lane, init_lane_train_state,
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.steps import (LaneTrainState, build_train_step_lane,
+                                init_lane_train_state,
                                 restore_lane_train_state)
 from repro.runtime.elastic import plan_elastic_mesh
 from repro.runtime.faults import FaultPlan, corrupt_leaf_file
@@ -267,6 +269,7 @@ def main(argv=None):
     ap.add_argument("--max-restarts", type=int, default=2,
                     help="in-process elastic restarts before giving up")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = resolve(args.arch, smoke=args.smoke)
     mesh0 = make_mesh_auto(args.batch,
@@ -395,6 +398,46 @@ def _commit_tuner_misses(args, tuner) -> None:
               file=sys.stderr, flush=True)
 
 
+def _lane_state(cfg, run, mesh, comm, seed: int):
+    """``(st, init)``: the run's master layout and a thunk that makes it.
+
+    ``st`` holds shapes, not arrays: its specs and checkpoint layout come
+    from an abstract trace of ``init_lane_train_state``.  ``init()`` runs
+    the same function under one jit whose outputs carry the mesh
+    shardings, so each array is created already sharded: no chip first
+    holds the whole params, ZeRO masters or moments, and no initial copy
+    stays alive beside the state the step is given.
+    """
+    key = jax.random.PRNGKey(seed)
+    static = {}
+    n, N = comm.topo.sizes(mesh)
+    # a ZeRO-3 stack is generated layer-sharded over the batch-axes chips
+    # (each chip draws its own L/p layers); GSPMD then re-lays it into the
+    # 1/p stripes with an all-to-all.  Left alone, every chip would draw
+    # the whole stack and slice its stripe out of it.
+    by_layer = (comm.param_layout(run.gradsync) == "zero3"
+                and cfg.num_layers % (n * N) == 0)
+    # the init program runs on an Auto-typed view of the mesh: with
+    # explicit axes the sharding types force the stack whole again
+    auto = Mesh(mesh.devices, mesh.axis_names)
+    layers = NamedSharding(auto, P(batch_axes(mesh)))
+
+    def arrays(k):
+        params = init_model(k, cfg)
+        if by_layer:
+            params["blocks"] = jax.tree.map(
+                lambda a: jax.lax.with_sharding_constraint(a, layers),
+                params["blocks"])
+        st = init_lane_train_state(cfg, run, mesh, params, comm=comm)
+        static["layout"] = (st.pspecs, st.ospecs, st.ckpt_layout)
+        return st.params, st.opt_state
+
+    params_t, opt_t = jax.eval_shape(arrays, key)
+    st = LaneTrainState(params_t, opt_t, *static["layout"])
+    init = jax.jit(arrays, out_shardings=st.to_shardings(mesh))
+    return st, lambda: init(key)
+
+
 def _run_attempt(args, cfg, plan: FaultPlan, mesh0, lost):
     """One attempt of the run on the mesh that survives ``lost``.
 
@@ -437,16 +480,15 @@ def _run_attempt(args, cfg, plan: FaultPlan, mesh0, lost):
     # single-batch-axis mesh), then the layout-matched master state
     step, comm = build_train_step_lane(cfg, run, opt_cfg, mesh, None,
                                        tuner=tuner)
-    params0 = init_model(jax.random.PRNGKey(args.seed), cfg)
-    st = init_lane_train_state(cfg, run, mesh, params0, comm=comm)
+    st, init_state = _lane_state(cfg, run, mesh, comm, args.seed)
     pshard, oshard = st.to_shardings(mesh)
 
     start_step = 0
     ckpt = AsyncCheckpointer(args.ckpt, layout=st.ckpt_layout) \
         if args.ckpt else None
     if args.ckpt and latest_step(args.ckpt) is not None:
-        # the host-side st trees are only the shape/layout targets here —
-        # don't device_put a full init state just to overwrite it.
+        # the st trees are only the shape/layout targets here — don't
+        # make a full init state just to overwrite it.
         # restore_lane_train_state handles BOTH same-kind restores and
         # cross-layout ones (a lane_zero3 checkpoint resuming under
         # lane_zero1 or a replicated strategy, and back) through the
@@ -458,8 +500,7 @@ def _run_attempt(args, cfg, plan: FaultPlan, mesh0, lost):
         print(f"resumed from step {start_step} "
               f"(layout {st.ckpt_layout.kind})")
     else:
-        params = jax.tree.map(jax.device_put, st.params, pshard)
-        opt_state = jax.tree.map(jax.device_put, st.opt_state, oshard)
+        params, opt_state = init_state()
 
     # fault/quorum machinery: the watchdog folds heartbeats (driven by
     # the fault plan; on a real fleet, by per-host progress counters)

@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                           + os.environ.get("XLA_FLAGS", ""))
-# The two lines above MUST run before any jax import (device count locks
-# at first backend init) — this module is a standalone CI entry point.
 """CI leg: the training driver must actually RUN every registered
 gradsync strategy, with a save → restore round-trip.
 
@@ -24,8 +19,8 @@ through the driver fails the build here too.
 
 Usage:  python -m repro.launch.train_smoke   (wired into ``make ci``)
 """
-import sys                                                    # noqa: E402
-import tempfile                                               # noqa: E402
+import sys
+import tempfile
 
 def main(argv=None) -> int:
     from repro.checkpoint import latest_step
@@ -79,4 +74,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # before the first jax import (main imports it): 8 CPU devices
+    from repro.tuning.backend import apply_backend_setup
+    apply_backend_setup("cpu", host_device_count=8)
     sys.exit(main(sys.argv[1:]))

@@ -111,7 +111,7 @@ class ShardedStack:
             # control; silently lowering it as a remat'd re-gather scan
             # would invalidate the control measurement
             raise ValueError(
-                "regather=True is incompatible with prefetch=False (the "
+                "regather=True cannot be combined with prefetch=False (the "
                 "blocking negative control); drop one of the two")
         self.shards = shards
         self.gather = gather
@@ -245,7 +245,7 @@ class StackLayout:
     leaf, whether ``adamw_update`` would weight-decay it (original
     ndim >= 2) — the flat per-element decay mask derives from it.
 
-    Derived via ``eval_shape``-compatible access (only ``.shape``/
+    Derived via ``eval_shape``-safe access (only ``.shape``/
     ``.dtype``/``.ndim`` of the leaves are read), so building a layout
     never materializes weights.
     """
@@ -259,7 +259,7 @@ class StackLayout:
         self.length = length            # L: rows in the stack
         self.stacked = stacked
 
-    # names the first ZeRO-3 port used (Zero3LayerSpec compatibility)
+    # names the first ZeRO-3 port used (the Zero3LayerSpec spelling)
     @property
     def layer_elems(self) -> int:
         return self.row_elems

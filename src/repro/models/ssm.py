@@ -102,10 +102,13 @@ def ssd_chunked(x, dt, A, B, C, *, chunk: int, init_state=None):
     seg_end = cum[:, :, -1, :]                            # (b,nc,H)
 
     # ---- intra-chunk (attention-like, causal) ----
-    # L[q1,q2] = exp(cum[q1]-cum[q2]) · (q1 ≥ q2)
+    # L[q1,q2] = exp(cum[q1]-cum[q2]) · (q1 ≥ q2).  The mask goes INSIDE
+    # the exp: above the diagonal diff is positive and overflows to inf,
+    # and where(mask, inf, 0) has a NaN gradient (0 · inf)
     diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (b,nc,Q,Q,H)
     causal = jnp.tril(jnp.ones((Q, Q), bool))
-    Lmat = jnp.where(causal[None, None, :, :, None], jnp.exp(diff), 0.0)
+    Lmat = jnp.exp(jnp.where(causal[None, None, :, :, None], diff,
+                             -jnp.inf))
     scores = jnp.einsum("bcqhs,bckhs->bcqkh", Cc, Bc) * Lmat
     y_intra = jnp.einsum("bcqkh,bckh,bckhp->bcqhp", scores, dtc, xc)
 

@@ -14,7 +14,10 @@ Correctness contracts pinned by tests/test_serve.py:
     to decoding each request alone at batch 1, across slot counts,
     admission orders and mid-stream refills — decode rows are
     independent and prefill is per-request batch-1, so batching is pure
-    throughput, never a semantic.
+    throughput, never a semantic.  Across slot counts this needs every
+    batch shape to round alike, as the CPU tests do; on a TPU in bf16 a
+    slots=1 step is another program and may flip a near-tied argmax, so
+    chip_smoke.py serves each request alone through the same step.
   * seeded replay: with a :class:`~repro.serve.sampling.SamplerConfig`,
     every token is a pure function of (seed, rid, position) — the same
     request replays bit-identically regardless of slot assignment or

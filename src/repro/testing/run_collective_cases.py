@@ -1,14 +1,14 @@
 """Subprocess entry point for the multi-device collective cases.
 
-Sets the host-device-count flag BEFORE any jax import, then delegates to
-repro.testing.collective_cases.main.  Never import this from pytest.
+Pins the CPU platform and the host-device count (8) before any jax
+backend use, then delegates to repro.testing.collective_cases.main.
+Never import this from pytest.
 """
-import os
 import sys
 
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=8 "
-    + os.environ.get("XLA_FLAGS", ""))
+from repro.tuning.backend import apply_backend_setup
+
+apply_backend_setup("cpu", host_device_count=8)
 
 from repro.testing.collective_cases import main  # noqa: E402
 

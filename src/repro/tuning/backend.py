@@ -19,6 +19,7 @@ the ones it does.
 from __future__ import annotations
 
 import os
+import sys
 from typing import MutableMapping, Optional
 
 __all__ = [
@@ -81,9 +82,13 @@ def apply_backend_setup(platform: str, *,
                         env: Optional[MutableMapping] = None) -> str:
     """Install this project's XLA flags for ``platform`` into
     ``env["XLA_FLAGS"]`` (default ``os.environ``) and return the final
-    string.  MUST run before the process's first ``import jax`` —
+    string.  MUST run before the process's first jax backend use —
     XLA_FLAGS is read once at backend initialization; changing it
     afterwards silently does nothing.
+
+    ``"cpu"`` also pins the platform (``JAX_PLATFORMS=cpu``): a CPU entry
+    point that simulates several devices must not take a TPU that happens
+    to be attached, see one device and fail in its mesh code.
     """
     if env is None:
         env = os.environ
@@ -91,4 +96,9 @@ def apply_backend_setup(platform: str, *,
         env.get("XLA_FLAGS", ""),
         xla_flags_for(platform, host_device_count=host_device_count))
     env["XLA_FLAGS"] = merged
+    if platform.lower() == "cpu":
+        env["JAX_PLATFORMS"] = "cpu"
+        if env is os.environ and "jax" in sys.modules:
+            # jax read JAX_PLATFORMS when it was imported
+            sys.modules["jax"].config.update("jax_platforms", "cpu")
     return merged

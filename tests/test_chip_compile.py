@@ -1,0 +1,69 @@
+"""The Pallas kernels compile for a TPU v5e at the published model widths.
+
+Nothing runs: each test lowers and compiles a kernel for a described (not
+attached) ``v5e:2x2`` chip, which is what the chip's compiler would refuse
+before any chip time is spent — block shapes off the (8, 128) tiling,
+primitives the kernel lowering lacks, more VMEM than a kernel may use.
+Interpret mode (tests/test_kernels.py) checks values; this file checks
+that the same kernels exist for the chip.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import resolve
+from repro.kernels.flash_attention import flash_attention_tpu
+from repro.kernels.ssd import ssd_tpu
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_flash_attention_compiles_at_h2o_danube_width(one_chip):
+    cfg = resolve("h2o-danube-3-4b")
+    H, K, hd, T = cfg.num_heads, cfg.num_kv_heads, cfg.hd(), 2048
+    assert (H, K, hd) == (32, 8, 120)
+    bf = jnp.bfloat16
+    _compile(lambda q, k, v: flash_attention_tpu(
+                 q, k, v, causal=True, window=cfg.sliding_window),
+             one_chip, ((1, H, T, hd), bf), ((1, K, T, hd), bf),
+             ((1, K, T, hd), bf))
+
+
+def test_ssd_compiles_at_mamba2_780m_width(one_chip):
+    cfg = resolve("mamba2-780m")
+    H, P, S, T = cfg.ssm_heads(), cfg.ssm_head_dim, cfg.ssm_state, 2048
+    assert (H, P, S) == (48, 64, 128)
+    b, bf, f32 = 4, jnp.bfloat16, jnp.float32
+    _compile(lambda x, dt, A, B, C: ssd_tpu(
+                 x, dt, A, B, C, chunk=cfg.ssm_chunk, heads_blk=8),
+             one_chip, ((b, H, T, P), bf), ((b, H, T), f32), ((H,), f32),
+             ((b, T, S), bf), ((b, T, S), bf))

@@ -82,3 +82,17 @@ def test_flash_attention_padding_kblocks():
     want = ref.attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_pallas_switch_raises_off_tpu(monkeypatch):
+    """REPRO_USE_PALLAS=1 on a backend that is not a TPU is an error, not
+    a quiet fall back to the reference; interpret mode stays available."""
+    from repro.kernels import ops
+    assert jax.default_backend() != "tpu"
+    monkeypatch.setenv("REPRO_USE_PALLAS", "1")
+    with pytest.raises(RuntimeError, match="not|backend"):
+        ops.use_pallas()
+    monkeypatch.setenv("REPRO_USE_PALLAS", "interpret")
+    assert ops.use_pallas()
+    monkeypatch.setenv("REPRO_USE_PALLAS", "0")
+    assert not ops.use_pallas()
