@@ -1,0 +1,9 @@
+"""Share of the traced window in which no op ran on a chip, averaged over
+the chips: 1 - (union of op intervals) / window."""
+from chipbench import trace
+
+
+def read(rec):
+    if rec["kind"] != "train" or "trace" not in rec:
+        return None
+    return 100.0 * trace.idle_share(rec["trace"])
