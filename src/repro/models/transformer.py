@@ -15,6 +15,13 @@ All parameters for scanned layers are stacked along a leading L dim
 (init via vmap over per-layer keys), so compile time is O(1) in depth and
 FSDP/TP shardings apply uniformly.  Serving uses functional caches threaded
 through the layer scan as scan xs/ys.
+
+Named scopes (``jax.named_scope``; metadata only, the compiled program is
+unchanged) mark each op's layer in its HLO ``op_name``: ``attn`` (norm,
+QKV, attention, output projection), ``kv_write`` inside it (the cache
+write), ``mlp`` (the feed-forward block).  Ops the compiler fuses into
+the layer scan's own stacking of its outputs take the scan's op_name and
+no scope.
 """
 from __future__ import annotations
 
@@ -135,34 +142,38 @@ def _norm(cfg, p, x):
 def _attn_noncache(lp, h, cfg: ModelConfig, *, causal: bool, positions,
                    window: int, kv=None):
     """Full-sequence attention (train / encoder / cross with given kv)."""
-    hn = _norm(cfg, lp["ln1"] if kv is None else lp["lnx"], h)
-    ap = lp["attn"] if kv is None else lp["xattn"]
-    if kv is None:
-        q, k, v = A.qkv(ap, hn, cfg, positions=positions, rope=True)
-    else:
-        q, _, _ = A.qkv(ap, hn, cfg, positions=positions, rope=False)
-        k, v = kv
-    o = A.attention_xla(q, k, v, causal=causal, window=window)
-    o = o.reshape(*o.shape[:2], -1) @ ap["wo"]
-    return h + o
+    with jax.named_scope("attn"):
+        hn = _norm(cfg, lp["ln1"] if kv is None else lp["lnx"], h)
+        ap = lp["attn"] if kv is None else lp["xattn"]
+        if kv is None:
+            q, k, v = A.qkv(ap, hn, cfg, positions=positions, rope=True)
+        else:
+            q, _, _ = A.qkv(ap, hn, cfg, positions=positions, rope=False)
+            k, v = kv
+        o = A.attention_xla(q, k, v, causal=causal, window=window)
+        o = o.reshape(*o.shape[:2], -1) @ ap["wo"]
+        return h + o
 
 
 def _ffn(lp, h, cfg: ModelConfig):
-    hn = _norm(cfg, lp["ln2"], h)
-    ctx = parallel_ctx()
-    if "moe" in lp:
-        if ctx.ep and ctx.ep_comm is not None:
-            out, aux = M.moe_block_ep(lp["moe"], hn, cfg, comm=ctx.ep_comm,
-                                      ep_blocks=ctx.ep_blocks,
-                                      strategy=ctx.ep_strategy)
-        else:
-            out, aux = M.moe_block(lp["moe"], hn, cfg)
-        return h + out, aux
-    if ctx.tp > 1 and ctx.tp_comm is not None:
-        tp_mlp = L.mlp_tp_reduce if ctx.tp_variant == "reduce" else L.mlp_tp
-        return h + tp_mlp(lp["mlp"], hn, cfg, comm=ctx.tp_comm,
-                          strategy=ctx.tp_strategy), 0.0
-    return h + L.mlp(lp["mlp"], hn, cfg), 0.0
+    with jax.named_scope("mlp"):
+        hn = _norm(cfg, lp["ln2"], h)
+        ctx = parallel_ctx()
+        if "moe" in lp:
+            if ctx.ep and ctx.ep_comm is not None:
+                out, aux = M.moe_block_ep(lp["moe"], hn, cfg,
+                                          comm=ctx.ep_comm,
+                                          ep_blocks=ctx.ep_blocks,
+                                          strategy=ctx.ep_strategy)
+            else:
+                out, aux = M.moe_block(lp["moe"], hn, cfg)
+            return h + out, aux
+        if ctx.tp > 1 and ctx.tp_comm is not None:
+            tp_mlp = L.mlp_tp_reduce if ctx.tp_variant == "reduce" \
+                else L.mlp_tp
+            return h + tp_mlp(lp["mlp"], hn, cfg, comm=ctx.tp_comm,
+                              strategy=ctx.tp_strategy), 0.0
+        return h + L.mlp(lp["mlp"], hn, cfg), 0.0
 
 
 def _dense_block(lp, h, cfg: ModelConfig, *, positions, enc_out=None):
@@ -529,35 +540,40 @@ def _attn_cached(lp, h, cfg: ModelConfig, lc, length, *, prefill: bool,
     Bz, T, _ = h.shape
     Smax = lc["k"].shape[1]
     positions = (jnp.arange(T)[None] if prefill else length[:, None])
-    hn = _norm(cfg, lp["ln1"], h)
-    q, k, v = A.qkv(lp["attn"], hn, cfg, positions=positions, rope=True)
-    if prefill:
-        newk = pin_kv(lax.dynamic_update_slice_in_dim(
-            lc["k"], pin_kv(k.astype(lc["k"].dtype)), 0, axis=1))
-        newv = pin_kv(lax.dynamic_update_slice_in_dim(
-            lc["v"], pin_kv(v.astype(lc["v"].dtype)), 0, axis=1))
-        o = A.attention_xla(q, k, v, causal=True, window=cfg.sliding_window)
-    else:
-        # one-hot select at per-row `length` (GSPMD-safe on a sharded S dim;
-        # pure select — an arithmetic blend promoted the stacked cache ys
-        # to fp32 on the CPU backend)
-        hot = (jnp.arange(Smax)[None, :] == length[:, None])        # (B,S)
-        newk = pin_kv(jnp.where(hot[..., None, None],
-                                k.astype(lc["k"].dtype), lc["k"]))
-        newv = pin_kv(jnp.where(hot[..., None, None],
-                                v.astype(lc["v"].dtype), lc["v"]))
-        o = A.decode_attention(q, newk, newv, length + 1,
-                               window=cfg.sliding_window)
-    o = o.reshape(Bz, T, -1) @ lp["attn"]["wo"]
-    h = h + o
-    if enc_kv is not None and "xattn" in lp:
-        hn = _norm(cfg, lp["lnx"], h)
-        qx, _, _ = A.qkv(lp["xattn"], hn, cfg, positions=positions, rope=False)
-        o = A.decode_attention(qx, enc_kv["k"], enc_kv["v"],
-                               jnp.full((Bz,), enc_kv["k"].shape[1])) \
-            if not prefill else \
-            A.attention_xla(qx, enc_kv["k"], enc_kv["v"], causal=False)
-        h = h + o.reshape(Bz, T, -1) @ lp["xattn"]["wo"]
+    with jax.named_scope("attn"):
+        hn = _norm(cfg, lp["ln1"], h)
+        q, k, v = A.qkv(lp["attn"], hn, cfg, positions=positions, rope=True)
+        if prefill:
+            with jax.named_scope("kv_write"):
+                newk = pin_kv(lax.dynamic_update_slice_in_dim(
+                    lc["k"], pin_kv(k.astype(lc["k"].dtype)), 0, axis=1))
+                newv = pin_kv(lax.dynamic_update_slice_in_dim(
+                    lc["v"], pin_kv(v.astype(lc["v"].dtype)), 0, axis=1))
+            o = A.attention_xla(q, k, v, causal=True,
+                                window=cfg.sliding_window)
+        else:
+            # one-hot select at per-row `length` (GSPMD-safe on a sharded S
+            # dim; pure select — an arithmetic blend promoted the stacked
+            # cache ys to fp32 on the CPU backend)
+            with jax.named_scope("kv_write"):
+                hot = (jnp.arange(Smax)[None, :] == length[:, None])  # (B,S)
+                newk = pin_kv(jnp.where(hot[..., None, None],
+                                        k.astype(lc["k"].dtype), lc["k"]))
+                newv = pin_kv(jnp.where(hot[..., None, None],
+                                        v.astype(lc["v"].dtype), lc["v"]))
+            o = A.decode_attention(q, newk, newv, length + 1,
+                                   window=cfg.sliding_window)
+        o = o.reshape(Bz, T, -1) @ lp["attn"]["wo"]
+        h = h + o
+        if enc_kv is not None and "xattn" in lp:
+            hn = _norm(cfg, lp["lnx"], h)
+            qx, _, _ = A.qkv(lp["xattn"], hn, cfg, positions=positions,
+                             rope=False)
+            o = A.decode_attention(qx, enc_kv["k"], enc_kv["v"],
+                                   jnp.full((Bz,), enc_kv["k"].shape[1])) \
+                if not prefill else \
+                A.attention_xla(qx, enc_kv["k"], enc_kv["v"], causal=False)
+            h = h + o.reshape(Bz, T, -1) @ lp["xattn"]["wo"]
     h, _ = _ffn(lp, h, cfg)
     return h, {"k": newk, "v": newv}
 

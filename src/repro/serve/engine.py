@@ -39,6 +39,7 @@ behind the length mask.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 import zlib
 from typing import Any, Optional
@@ -46,6 +47,7 @@ from typing import Any, Optional
 import numpy as np
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from .sampling import SamplerConfig, sample_token
 from .steps import ServeStep, build_serve_step
@@ -54,6 +56,32 @@ __all__ = ["Request", "ContinuousBatcher", "termination_reason",
            "DEFAULT_BUCKETS"]
 
 DEFAULT_BUCKETS = (32, 64, 128, 256, 512)
+
+# Host spans (``jax.profiler.TraceAnnotation``), written into a running
+# profiler's trace beside the device's ops; with no profiler running each
+# costs one TraceMe check:
+#   serve.admit    all of ``admit``; serve.decode  all of ``step_decode``
+#   inside both    serve.dispatch (the call into the step), serve.fetch (the
+#                  logits to the host, waiting on the device), serve.sample
+#                  (sampling and termination); in admit also serve.splice
+#   host.gc        each collection of Python's garbage collector
+_gc_open: list = []
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    if phase == "start":
+        span = TraceAnnotation("host.gc")
+        span.__enter__()
+        _gc_open.append(span)
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+def _install_gc_span() -> None:
+    """Put a ``host.gc`` span round every collection (once a process)."""
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
+
 
 # families whose serving state is a recurrence over every consumed token
 # (pad tokens would corrupt it) — prefilled at exact prompt length
@@ -157,6 +185,7 @@ class ContinuousBatcher:
             import jax
             self._sample_fn = jax.jit(
                 lambda row, rid, pos: sample_token(row, sampler, rid, pos))
+        _install_gc_span()
 
     # -- sampling / termination ------------------------------------------
 
@@ -219,38 +248,46 @@ class ContinuousBatcher:
         """Prefill ``req`` at batch 1 and splice its state into ``slot``.
         Produces the first generated token (from the last TRUE prompt
         position).  Raises ValueError when the request cannot fit
-        ``max_seq``."""
-        prompt = np.asarray(req.prompt, np.int32).reshape(-1)
-        L = int(prompt.shape[0])
-        if L == 0:
-            raise ValueError(f"request {req.rid!r}: empty prompt")
-        need = self._prefix + L + int(req.max_new_tokens)
-        if need > self.max_seq:
-            raise ValueError(
-                f"request {req.rid!r}: prompt length {L}"
-                + (f" + {self._prefix} vision tokens"
-                   if self._prefix else "")
-                + f" + max_new_tokens {req.max_new_tokens} = {need} "
-                f"exceeds max_seq={self.max_seq}; shorten the prompt or "
-                f"lower max_new_tokens")
-        b = self._bucket_for(L)
-        if b < L:
-            raise RuntimeError(
-                f"prefill bucket {b} shorter than prompt length {L}")
-        toks = np.zeros((1, b), np.int32)
-        toks[0, :L] = prompt          # whole prompt, never sliced
-        logits, st1 = self.step.prefill(self.hosted, jnp.asarray(toks), L,
-                                        self._extra_embeds(req))
+        ``max_seq``.  A request with no arrival stamp is stamped on entry,
+        so its ``ttft_ms`` holds its own prefill."""
         if req.t_arrival is None:
             req.t_arrival = time.perf_counter()
-        t = self._next_token(np.asarray(logits)[0, -1], req)
-        req.out.append(t)
-        req.t_first = time.perf_counter()
-        if self._finish_if_done(req, t, self._prefix + L):
-            return
-        self.state = self.step.splice(self.state, st1, slot)
-        self._active[slot] = req
-        self._last_tok[slot] = t
+        with TraceAnnotation("serve.admit"):
+            prompt = np.asarray(req.prompt, np.int32).reshape(-1)
+            L = int(prompt.shape[0])
+            if L == 0:
+                raise ValueError(f"request {req.rid!r}: empty prompt")
+            need = self._prefix + L + int(req.max_new_tokens)
+            if need > self.max_seq:
+                raise ValueError(
+                    f"request {req.rid!r}: prompt length {L}"
+                    + (f" + {self._prefix} vision tokens"
+                       if self._prefix else "")
+                    + f" + max_new_tokens {req.max_new_tokens} = {need} "
+                    f"exceeds max_seq={self.max_seq}; shorten the prompt or "
+                    f"lower max_new_tokens")
+            b = self._bucket_for(L)
+            if b < L:
+                raise RuntimeError(
+                    f"prefill bucket {b} shorter than prompt length {L}")
+            toks = np.zeros((1, b), np.int32)
+            toks[0, :L] = prompt          # whole prompt, never sliced
+            with TraceAnnotation("serve.dispatch"):
+                logits, st1 = self.step.prefill(
+                    self.hosted, jnp.asarray(toks), L,
+                    self._extra_embeds(req))
+            with TraceAnnotation("serve.fetch"):
+                row = np.asarray(logits)[0, -1]
+            with TraceAnnotation("serve.sample"):
+                t = self._next_token(row, req)
+                req.out.append(t)
+                req.t_first = time.perf_counter()
+                if self._finish_if_done(req, t, self._prefix + L):
+                    return
+            with TraceAnnotation("serve.splice"):
+                self.state = self.step.splice(self.state, st1, slot)
+            self._active[slot] = req
+            self._last_tok[slot] = t
 
     # -- decode -----------------------------------------------------------
 
@@ -258,20 +295,25 @@ class ContinuousBatcher:
         """One batched decode over every slot (idle slots carry garbage
         rows; decode rows are independent so they cannot influence the
         active ones).  Returns the number of tokens appended."""
-        tok = jnp.asarray(self._last_tok.reshape(self.slots, 1))
-        logits, self.state = self.step.decode(self.hosted, tok, self.state)
-        rows = np.asarray(logits)
-        lengths = np.asarray(self.state.length)
-        produced = 0
-        for slot, req in list(self._active.items()):
-            t = self._next_token(rows[slot, -1], req)
-            req.out.append(t)
-            self._last_tok[slot] = t
-            produced += 1
-            if self._finish_if_done(req, t, int(lengths[slot])):
-                del self._active[slot]
-                self._free.append(slot)
-        return produced
+        with TraceAnnotation("serve.decode"):
+            with TraceAnnotation("serve.dispatch"):
+                tok = jnp.asarray(self._last_tok.reshape(self.slots, 1))
+                logits, self.state = self.step.decode(self.hosted, tok,
+                                                      self.state)
+            with TraceAnnotation("serve.fetch"):
+                rows = np.asarray(logits)
+                lengths = np.asarray(self.state.length)
+            with TraceAnnotation("serve.sample"):
+                produced = 0
+                for slot, req in list(self._active.items()):
+                    t = self._next_token(rows[slot, -1], req)
+                    req.out.append(t)
+                    self._last_tok[slot] = t
+                    produced += 1
+                    if self._finish_if_done(req, t, int(lengths[slot])):
+                        del self._active[slot]
+                        self._free.append(slot)
+            return produced
 
     # -- the serving loop -------------------------------------------------
 
