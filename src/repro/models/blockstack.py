@@ -193,9 +193,13 @@ def scan_stack_cached(stack: ShardedStack, h, xs, body):
     ``body(h, layer_params, xs_row) -> (h', ys_row)`` where ``xs`` and
     the returned ``ys`` are pytrees whose every leaf has a leading
     stack dim L (``xs_row``/``ys_row`` are single rows of them) — the
-    cached prefill/decode bodies thread (cache_in -> cache_out), and the
-    audio prefill additionally emits the per-layer cross-attention K/V.
-    No aux scalars, no layer index, no regather (inference has no
+    cached prefill bodies thread (cache_in -> cache_out), and the audio
+    prefill additionally emits the per-layer cross-attention K/V; the
+    decode body takes its layer index from ``xs``, reads that layer of the
+    cache it closes over, and emits only the layer's new cache rows, which
+    the caller writes into the cache in place after the scan (so neither
+    the stacked ``ys`` nor the concatenation with layer L-1's row is
+    cache-sized).  No aux scalars, no regather (inference has no
     backward): just the same one-layer prefetch structure — layer i+1's
     all-gather issued alongside layer i's compute, layer L-1 outside the
     loop so exactly L gathers run.  Returns ``(h, ys)``.

@@ -13,15 +13,15 @@ Families
 
 All parameters for scanned layers are stacked along a leading L dim
 (init via vmap over per-layer keys), so compile time is O(1) in depth and
-FSDP/TP shardings apply uniformly.  Serving uses functional caches threaded
-through the layer scan as scan xs/ys.
+FSDP/TP shardings apply uniformly.  Serving uses functional caches:
+prefill threads them through the layer scan as scan xs/ys; decode reads
+each layer of the stacked K/V cache, emits only the new rows, and writes
+them into the cache in place after the scan (``_kv_write``).
 
 Named scopes (``jax.named_scope``; metadata only, the compiled program is
 unchanged) mark each op's layer in its HLO ``op_name``: ``attn`` (norm,
 QKV, attention, output projection), ``kv_write`` inside it (the cache
-write), ``mlp`` (the feed-forward block).  Ops the compiler fuses into
-the layer scan's own stacking of its outputs take the scan's op_name and
-no scope.
+write), ``mlp`` (the feed-forward block).
 """
 from __future__ import annotations
 
@@ -530,12 +530,66 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
     raise ValueError(cfg.family)
 
 
+def _seq_sharded() -> bool:
+    """Whether the serve-cache layout (``pin_kv``) shards the cache's S dim:
+    a read or write at a traced position there could make GSPMD gather the
+    cache, so the decode keeps to one-hot selects over S."""
+    spec = L._ACT_KV.get()
+    return spec is not None and spec[1] is not None
+
+
+def _kv_last_rows(cache):
+    """{"k","v"} (n, B, K, hd): each layer's and slot's row at position
+    S-1 of the stacked cache as the decode step finds it, for
+    :func:`_kv_write` to write back where a slot is at or past S.  None
+    where the S dim is sharded (the whole-cache select there needs none)."""
+    if _seq_sharded():
+        return None
+    return {n: cache[n][:, :, -1] for n in ("k", "v")}
+
+
+def _kv_write(cache, rows, length):
+    """Write the decode step's new rows ``rows`` {"k","v"} (n, B, K, hd)
+    into the stacked cache (n, B, S, K, hd) at position ``length[b]`` of
+    each slot, every layer at once, after the layer loop.
+
+    One dynamic_update_slice per slot, in place in the (donated) cache: B
+    writes of n rows, not a rewrite of the stack.  A straight chain of
+    them, not a scatter: the chip stores the cache with S minor, and a
+    scatter (or a loop, a cond, a gather) made the compiler relayout, and
+    so copy, the whole cache.  A slot at or past S
+    (idle slots keep counting ``length`` up) has the slice's start clamped
+    to S-1, and its rows there are the ones it found (:func:`_kv_last_rows`
+    via the layer body), so nothing changes.  Nothing reads the cache
+    once the writes begin, so the compiler needs no copy of it.  Where the
+    S dim is sharded, a one-hot select over the whole cache instead.
+    """
+    S = cache["k"].shape[2]
+    if _seq_sharded():
+        hot = (jnp.arange(S) == length[:, None])[None, :, :, None, None]
+        return {n: jnp.where(hot, rows[n][:, :, None].astype(a.dtype), a)
+                for n, a in cache.items()}
+    pos = jnp.minimum(length, S - 1)
+    out = {}
+    for n, a in cache.items():
+        for b in range(length.shape[0]):
+            a = lax.dynamic_update_slice(
+                a, rows[n][:, b, None, None].astype(a.dtype),
+                (0, b, pos[b], 0, 0))
+        out[n] = a
+    return out
+
+
 def _attn_cached(lp, h, cfg: ModelConfig, lc, length, *, prefill: bool,
-                 enc_kv=None):
+                 enc_kv=None, last=None):
     """Attention with cache read/write.  h: (B,T,d); lc: {"k","v"} (B,S,K,hd).
 
-    prefill: writes positions [0, T) and attends within the new block.
-    decode:  T == 1, writes at `length`, attends to the whole cache.
+    prefill: writes positions [0, T) and attends within the new block;
+             returns the new ``lc``.
+    decode:  T == 1, attends to the cache with the new row selected in at
+             ``length``, and returns only the new rows {"k","v"} (B,K,hd)
+             (where a slot is at or past S, its row of ``last``, this
+             layer's :func:`_kv_last_rows`) for :func:`_kv_write`.
     """
     Bz, T, _ = h.shape
     Smax = lc["k"].shape[1]
@@ -549,18 +603,25 @@ def _attn_cached(lp, h, cfg: ModelConfig, lc, length, *, prefill: bool,
                     lc["k"], pin_kv(k.astype(lc["k"].dtype)), 0, axis=1))
                 newv = pin_kv(lax.dynamic_update_slice_in_dim(
                     lc["v"], pin_kv(v.astype(lc["v"].dtype)), 0, axis=1))
+            newc = {"k": newk, "v": newv}
             o = A.attention_xla(q, k, v, causal=True,
                                 window=cfg.sliding_window)
         else:
             # one-hot select at per-row `length` (GSPMD-safe on a sharded S
-            # dim; pure select — an arithmetic blend promoted the stacked
-            # cache ys to fp32 on the CPU backend)
+            # dim; pure select — an arithmetic blend promoted the cache to
+            # fp32 on the CPU backend)
             with jax.named_scope("kv_write"):
                 hot = (jnp.arange(Smax)[None, :] == length[:, None])  # (B,S)
                 newk = pin_kv(jnp.where(hot[..., None, None],
                                         k.astype(lc["k"].dtype), lc["k"]))
                 newv = pin_kv(jnp.where(hot[..., None, None],
                                         v.astype(lc["v"].dtype), lc["v"]))
+                newc = {"k": k[:, 0].astype(lc["k"].dtype),
+                        "v": v[:, 0].astype(lc["v"].dtype)}
+                if last is not None:
+                    past = (length >= Smax)[:, None, None]
+                    newc = {n: jnp.where(past, last[n], r)
+                            for n, r in newc.items()}
             o = A.decode_attention(q, newk, newv, length + 1,
                                    window=cfg.sliding_window)
         o = o.reshape(Bz, T, -1) @ lp["attn"]["wo"]
@@ -575,7 +636,7 @@ def _attn_cached(lp, h, cfg: ModelConfig, lc, length, *, prefill: bool,
                 A.attention_xla(qx, enc_kv["k"], enc_kv["v"], causal=False)
             h = h + o.reshape(Bz, T, -1) @ lp["xattn"]["wo"]
     h, _ = _ffn(lp, h, cfg)
-    return h, {"k": newk, "v": newv}
+    return h, newc
 
 
 def _scan_enc_kv(params, cfg, enc_out):
@@ -700,6 +761,12 @@ def prefill(params, cfg: ModelConfig, tokens, cache, *, extra_embeds=None,
 def decode_step(params, cfg: ModelConfig, token, state: ServeState):
     """One token for every sequence.  token: (B, 1) int32.
 
+    The attention families read each layer's cache from the stacked cache
+    (the layer index comes from xs), emit only the layer's new rows from
+    the layer scan, and write all of them into the donated cache in place
+    after it (:func:`_kv_write`): the step moves B x layers rows into the
+    cache, not the cache.
+
     Like :func:`prefill`, ``params["blocks"]`` may be a
     :class:`ShardedStack`: layer i+1's 1/p weight gather is issued
     alongside layer i's cached attention (``scan_stack_cached``) — the
@@ -707,41 +774,38 @@ def decode_step(params, cfg: ModelConfig, token, state: ServeState):
     """
     h = L.embed(params["embed"], token)
     length = state.length
+    enc_kv = state.enc_kv
 
-    if isinstance(params.get("blocks"), ShardedStack):
-        if cfg.family in _SCANNED_FAMILIES:
-            xs = state.cache if state.enc_kv is None else \
-                (state.cache, state.enc_kv)
+    def attn_body(h, lp, x):
+        i, last, ekv = x
+        lc = {n: lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+              for n, a in state.cache.items()}
+        return _attn_cached(lp, h, cfg, lc, length, prefill=False,
+                            enc_kv=ekv, last=last)
 
-            def body(h, lp, xrow):
-                lc, ekv = (xrow, None) if state.enc_kv is None else xrow
-                h, newc = _attn_cached(lp, h, cfg, lc, length,
-                                       prefill=False, enc_kv=ekv)
-                return h, newc
-            h, newcache = scan_stack_cached(params["blocks"], h, xs, body)
-        elif cfg.family == "ssm":
-            def body(h, lp, lc):
-                hn = _norm(cfg, lp["ln1"], h)
-                out, st = S.mamba2_block(lp["mamba"], hn, cfg, state=lc)
-                return h + out, st
-            h, newcache = scan_stack_cached(params["blocks"], h,
-                                            state.cache, body)
+    if cfg.family in _SCANNED_FAMILIES:
+        xs = (jnp.arange(cfg.num_layers), _kv_last_rows(state.cache),
+              enc_kv)
+        if isinstance(params.get("blocks"), ShardedStack):
+            h, rows = scan_stack_cached(params["blocks"], h, xs, attn_body)
         else:
+            h, rows = lax.scan(lambda h, x: attn_body(h, *x), h,
+                               (params["blocks"], xs))
+        with jax.named_scope("attn"), jax.named_scope("kv_write"):
+            newcache = _kv_write(state.cache, rows, length)
+    elif isinstance(params.get("blocks"), ShardedStack):
+        if cfg.family != "ssm":
             raise ValueError(
                 f"family {cfg.family!r} cannot serve from a ShardedStack "
                 f"(the hybrid grouped attention cache does not fit the "
                 f"flat layer scan); host it replicated")
-    elif cfg.family in _SCANNED_FAMILIES:
-        xs = (params["blocks"], state.cache) if state.enc_kv is None else \
-             (params["blocks"], state.cache, state.enc_kv)
 
-        def body(h, lpc):
-            lp, lc = lpc[0], lpc[1]
-            ekv = lpc[2] if len(lpc) == 3 else None
-            h, newc = _attn_cached(lp, h, cfg, lc, length, prefill=False,
-                                   enc_kv=ekv)
-            return h, newc
-        h, newcache = lax.scan(body, h, xs)
+        def body(h, lp, lc):
+            hn = _norm(cfg, lp["ln1"], h)
+            out, st = S.mamba2_block(lp["mamba"], hn, cfg, state=lc)
+            return h + out, st
+        h, newcache = scan_stack_cached(params["blocks"], h, state.cache,
+                                        body)
     elif cfg.family == "ssm":
         def body(h, lpc):
             lp, lc = lpc
@@ -758,11 +822,15 @@ def decode_step(params, cfg: ModelConfig, token, state: ServeState):
     h = _norm(cfg, params["final_norm"], h)
     logits = L.unembed(params["embed"], h)
     new_state = ServeState(cache=newcache, length=length + 1,
-                           enc_kv=state.enc_kv)
+                           enc_kv=enc_kv)
     return logits, new_state
 
 
 def _hybrid_cached(params, cfg: ModelConfig, h, cache, length, *, prefill):
+    """Zamba2 cached pass: the shared attention block, then the group's
+    Mamba2 layers.  Prefill threads the grouped attention cache through
+    xs/ys; decode emits its new rows and writes them after the group
+    scan, as :func:`decode_step`."""
     groups, every, tail = _hybrid_split(cfg)
     shared = params["shared_attn"]
     head = _tree_first(params["blocks"], groups * every)
@@ -770,6 +838,8 @@ def _hybrid_cached(params, cfg: ModelConfig, h, cache, length, *, prefill):
     mcache_head = _tree_first(cache["mamba"], groups * every)
     mcache_head = jax.tree.map(
         lambda a: a.reshape(groups, every, *a.shape[1:]), mcache_head)
+    attn = cache["attn"]
+    last = None if prefill else _kv_last_rows(attn)
 
     def mamba_body(h, lpc):
         lp, lc = lpc
@@ -778,14 +848,19 @@ def _hybrid_cached(params, cfg: ModelConfig, h, cache, length, *, prefill):
         return h + out, st
 
     def group_body(h, gx):
-        gp, gmc, gac = gx
-        h, newac = _attn_cached_shared(shared, h, cfg, gac, length,
-                                       prefill=prefill)
+        gp, gmc, g, glast = gx
+        gac = {n: lax.dynamic_index_in_dim(a, g, 0, keepdims=False)
+               for n, a in attn.items()}
+        h, newac = _attn_cached(shared, h, cfg, gac, length,
+                                prefill=prefill, last=glast)
         h, newmc = lax.scan(mamba_body, h, (gp, gmc))
         return h, (newmc, newac)
 
     h, (new_mc_head, new_ac) = lax.scan(
-        group_body, h, (head, mcache_head, cache["attn"]))
+        group_body, h, (head, mcache_head, jnp.arange(groups), last))
+    if not prefill:
+        with jax.named_scope("attn"), jax.named_scope("kv_write"):
+            new_ac = _kv_write(attn, new_ac, length)
     new_mc_head = jax.tree.map(
         lambda a: a.reshape(groups * every, *a.shape[2:]), new_mc_head)
     if tail:
@@ -797,11 +872,6 @@ def _hybrid_cached(params, cfg: ModelConfig, h, cache, length, *, prefill):
     else:
         new_mc = new_mc_head
     return h, {"mamba": new_mc, "attn": new_ac}
-
-
-def _attn_cached_shared(shared, h, cfg, lc, length, *, prefill):
-    h, newc = _attn_cached(shared, h, cfg, lc, length, prefill=prefill)
-    return h, newc
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""The Pallas kernels compile for a TPU v5e at the published model widths.
+"""The Pallas kernels compile for a TPU v5e at the published model widths,
+and the serving decode at the benchmark's size keeps its cache in place.
 
 Nothing runs: each test lowers and compiles a kernel for a described (not
 attached) ``v5e:2x2`` chip, which is what the chip's compiler would refuse
@@ -12,6 +13,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import pytest
 
@@ -67,3 +69,33 @@ def test_ssd_compiles_at_mamba2_780m_width(one_chip):
                  x, dt, A, B, C, chunk=cfg.ssm_chunk, heads_blk=8),
              one_chip, ((b, H, T, P), bf), ((b, H, T), f32), ((H,), f32),
              ((b, T, S), bf), ((b, T, S), bf))
+
+
+def test_decode_writes_the_cache_in_place_at_h2o_danube_width(one_chip):
+    """The replicated decode at 16 slots x 2048 positions: no op but an
+    in-place dynamic-update-slice makes a whole [layers, slots, S, K, hd]
+    K or V stack (the chip keeps the stack with S minor; a write that
+    made the compiler relayout it would copy all of it), and its
+    temporaries stay far below one stack."""
+    from repro.models import init_model
+    from repro.serve import build_serve_step
+    cfg = resolve("h2o-danube-3-4b")
+    slots, max_seq = 16, 2048
+    step = build_serve_step(cfg, max_seq=max_seq, slots=slots)
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: init_model(jax.random.PRNGKey(0), cfg)))
+    state = on_chip(jax.eval_shape(step.init_state))
+    tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    compiled = step.decode.lower(params, tok, state).compile()
+    k = state.cache["k"]
+    assert k.shape == (24, 16, 2048, 8, 120)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < k.size * k.dtype.itemsize // 100
+    stack = re.escape("[" + ",".join(map(str, k.shape)) + "]")
+    ops = set(re.findall(r"= bf16" + stack + r"\S* ([\w-]+)\(",
+                         compiled.as_text()))
+    assert ops <= {"parameter", "get-tuple-element",
+                   "dynamic-update-slice"}, ops

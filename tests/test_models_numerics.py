@@ -139,3 +139,256 @@ def test_moe_capacity_overflow_drops_gracefully():
     x = jnp.ones((1, 32, cfg.d_model), jnp.float32)   # all tokens identical
     out, _ = M.moe_block(params, x, cfg)              # severe overflow
     assert np.isfinite(np.asarray(out)).all()
+
+
+# ---------------------------------------------------------------------------
+# decode's in-place cache write == the one-hot select it replaced
+# ---------------------------------------------------------------------------
+
+KV_FAMILIES = ("dense", "moe", "vlm", "audio", "hybrid")
+KV_MAX_SEQ = 160
+KV_STEPS = 3
+
+
+def _onehot_attn(lp, h, cfg, lc, length, ekv):
+    """One layer of the decode with its own (B, S, K, hd) cache in and out
+    and the write as a one-hot select over all S positions."""
+    from repro.models import attention as A
+    from repro.models import transformer as T
+    Bz = h.shape[0]
+    positions = length[:, None]
+    hn = T._norm(cfg, lp["ln1"], h)
+    q, k, v = A.qkv(lp["attn"], hn, cfg, positions=positions, rope=True)
+    hot = (jnp.arange(lc["k"].shape[1])[None, :]
+           == length[:, None])[..., None, None]
+    new = {n: jnp.where(hot, x.astype(lc[n].dtype), lc[n])
+           for n, x in (("k", k), ("v", v))}
+    o = A.decode_attention(q, new["k"], new["v"], length + 1,
+                           window=cfg.sliding_window)
+    h = h + o.reshape(Bz, 1, -1) @ lp["attn"]["wo"]
+    if ekv is not None:
+        hn = T._norm(cfg, lp["lnx"], h)
+        qx, _, _ = A.qkv(lp["xattn"], hn, cfg, positions=positions,
+                         rope=False)
+        o = A.decode_attention(qx, ekv["k"], ekv["v"],
+                               jnp.full((Bz,), ekv["k"].shape[1]))
+        h = h + o.reshape(Bz, 1, -1) @ lp["xattn"]["wo"]
+    h, _ = T._ffn(lp, h, cfg)
+    return h, new
+
+
+def _onehot_hybrid(params, cfg, h, cache, length):
+    from repro.models import ssm as S
+    from repro.models import transformer as T
+    groups, every, tail = T._hybrid_split(cfg)
+    n = groups * every
+    grouped = lambda t: jax.tree.map(
+        lambda a: a[:n].reshape(groups, every, *a.shape[1:]), t)
+
+    def mamba(h, x):
+        lp, lc = x
+        out, st = S.mamba2_block(lp["mamba"], T._norm(cfg, lp["ln1"], h),
+                                 cfg, state=lc)
+        return h + out, st
+
+    def group(h, x):
+        gp, gmc, gac = x
+        h, ac = _onehot_attn(params["shared_attn"], h, cfg, gac, length,
+                             None)
+        h, mc = jax.lax.scan(mamba, h, (gp, gmc))
+        return h, (mc, ac)
+
+    h, (mc, ac) = jax.lax.scan(group, h, (grouped(params["blocks"]),
+                                          grouped(cache["mamba"]),
+                                          cache["attn"]))
+    mc = jax.tree.map(lambda a: a.reshape(n, *a.shape[2:]), mc)
+    if tail:
+        h, mt = jax.lax.scan(mamba, h, jax.tree.map(
+            lambda a: a[n:], (params["blocks"], cache["mamba"])))
+        mc = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), mc, mt)
+    return h, {"mamba": mc, "attn": ac}
+
+
+def _onehot_decode(params, cfg, token, state):
+    """The decode as it was before the in-place write: every layer's cache
+    row goes in through the scan's xs and comes back stacked as its ys."""
+    from repro.models import layers as Lyr
+    from repro.models import transformer as T
+    from repro.models.blockstack import ShardedStack, scan_stack_cached
+    h = Lyr.embed(params["embed"], token)
+    length = state.length
+    if cfg.family == "hybrid":
+        h, cache = _onehot_hybrid(params, cfg, h, state.cache, length)
+    else:
+        xs = (state.cache,) if state.enc_kv is None \
+            else (state.cache, state.enc_kv)
+
+        def body(h, lp, x):
+            return _onehot_attn(lp, h, cfg, x[0], length,
+                                x[1] if len(x) == 2 else None)
+        if isinstance(params["blocks"], ShardedStack):
+            h, cache = scan_stack_cached(params["blocks"], h, xs, body)
+        else:
+            h, cache = jax.lax.scan(lambda h, x: body(h, x[0], x[1:]), h,
+                                    (params["blocks"], *xs))
+    h = T._norm(cfg, params["final_norm"], h)
+    return Lyr.unembed(params["embed"], h), T.ServeState(
+        cache=cache, length=length + 1, enc_kv=state.enc_kv)
+
+
+def _kv_leaves(cfg, cache):
+    return cache["attn"] if cfg.family == "hybrid" else cache
+
+
+def _kv_setup(family, slots, seed=0):
+    """Smoke config, weights, a state whose caches hold random values (so
+    a stray write shows), and slots at 0, 7, max_seq - 1 and past max_seq."""
+    from repro.models import init_model
+    from repro.models.blockstack import family_smoke_archs
+    from repro.serve.steps import _init_serve_state
+    cfg = resolve(family_smoke_archs()[family], smoke=True)
+    params = init_model(jax.random.PRNGKey(seed), cfg)
+    state = _init_serve_state(cfg, slots, KV_MAX_SEQ)
+    leaves, tdef = jax.tree.flatten(state)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
+    leaves = [jax.random.normal(k, x.shape).astype(x.dtype)
+              if jnp.issubdtype(x.dtype, jnp.floating) else x
+              for k, x in zip(keys, leaves)]
+    state = jax.tree.unflatten(tdef, leaves)
+    lengths = ([0, 7, KV_MAX_SEQ - 1, KV_MAX_SEQ + 3] * slots)[:slots]
+    state.length = jnp.asarray(lengths, jnp.int32)
+    tokens = jax.random.randint(jax.random.PRNGKey(seed + 2),
+                                (KV_STEPS, slots, 1), 0, cfg.vocab_size)
+    return cfg, params, state, tokens
+
+
+def _assert_steps_equal(cfg, run, ref, state, tokens):
+    """``run`` and ``ref`` ((tok, state) -> (logits, state)) agree bitwise
+    over the decode steps, and a slot at or past max_seq writes nothing."""
+    a = b = state
+    for t in range(tokens.shape[0]):
+        before = jax.tree.map(np.asarray, _kv_leaves(cfg, a.cache))
+        past = np.asarray(a.length) >= KV_MAX_SEQ
+        la, a = run(tokens[t], a)
+        lb, b = ref(tokens[t], b)
+        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        for old, new in zip(jax.tree.leaves(before),
+                            jax.tree.leaves(_kv_leaves(cfg, a.cache))):
+            np.testing.assert_array_equal(np.asarray(new)[:, past],
+                                          old[:, past])
+        assert past.any()
+
+
+@pytest.mark.parametrize("family", KV_FAMILIES)
+def test_decode_inplace_write_matches_onehot_replicated(family):
+    from repro.models import decode_step
+    cfg, params, state, tokens = _kv_setup(family, slots=4)
+    run = jax.jit(lambda p, t, s: decode_step(p, cfg, t, s))
+    ref = jax.jit(lambda p, t, s: _onehot_decode(p, cfg, t, s))
+    _assert_steps_equal(cfg, lambda t, s: run(params, t, s),
+                        lambda t, s: ref(params, t, s), state, tokens)
+
+
+def zero3_kv_case(family):
+    """lane_zero3 hosting on a 4-device mesh: the hosted decode with the
+    in-place write against the same hosting with the one-hot decode.  Runs
+    in a process that sees 4 CPU devices."""
+    import repro.serve.steps as steps
+    from repro.serve import build_serve_step
+    cfg, params, state, tokens = _kv_setup(family, slots=4)
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]).reshape(2, 2, 1),
+                             ("pod", "data", "model"))
+
+    def hosted():
+        step = build_serve_step(cfg, max_seq=KV_MAX_SEQ, slots=4,
+                                hosting="lane_zero3", mesh=mesh)
+        w, like = step.prepare(params), step.init_state()
+        # the step donates its state: hand it a placed copy, keep ours
+        return lambda t, s: step.decode(w, t, jax.tree.map(
+            lambda x, z: jax.device_put(np.asarray(x), z.sharding), s,
+            like))
+
+    run = hosted()
+    orig, steps.decode_step = steps.decode_step, _onehot_decode
+    try:
+        ref = hosted()
+        ref(tokens[0], state)          # traces the one-hot decode
+    finally:
+        steps.decode_step = orig
+    _assert_steps_equal(cfg, run, ref, state, tokens)
+
+
+ZERO3_KV_FAMILIES = tuple(f for f in KV_FAMILIES if f != "hybrid")
+_ZERO3_KV = None
+
+
+def _zero3_kv_results():
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    here = pathlib.Path(__file__).resolve().parent
+    code = ("import sys\n"
+            "from repro.tuning.backend import apply_backend_setup\n"
+            "apply_backend_setup('cpu', host_device_count=4)\n"
+            "import test_models_numerics as t\n"
+            "for f in sys.argv[1:]:\n"
+            "    try:\n"
+            "        t.zero3_kv_case(f)\n"
+            "        print('PASS', f)\n"
+            "    except Exception as e:\n"
+            "        print('FAIL', f, repr(e)[:2000].replace('\\n', ' '))\n")
+    path = [str(here.parent / "src"), str(here), os.environ.get("PYTHONPATH")]
+    env = {**os.environ,
+           "PYTHONPATH": os.pathsep.join(p for p in path if p)}
+    proc = subprocess.run([sys.executable, "-c", code, *ZERO3_KV_FAMILIES],
+                          capture_output=True, text=True, timeout=1200,
+                          env=env)
+    out = {line.split()[1]: line for line in proc.stdout.splitlines()
+           if line.startswith(("PASS ", "FAIL "))}
+    out["__stderr__"] = proc.stderr[-3000:]
+    return out
+
+
+@pytest.mark.parametrize("family", ZERO3_KV_FAMILIES)
+def test_decode_inplace_write_matches_onehot_lane_zero3(family):
+    global _ZERO3_KV
+    if _ZERO3_KV is None:
+        _ZERO3_KV = _zero3_kv_results()
+    line = _ZERO3_KV.get(family, "no result:\n" + _ZERO3_KV["__stderr__"])
+    assert line.startswith("PASS"), line
+
+
+def test_compiled_decode_writes_the_cache_in_place():
+    """The replicated decode (smoke dense config, 8 layers, 8 slots x 512
+    positions, so one K stack outweighs one layer's own temporaries) holds
+    no copy of the stacked cache, needs less temporary memory than one K
+    stack, and returns the cache in the buffers it was donated."""
+    import dataclasses
+    import re
+    from repro.models import init_model
+    from repro.serve import build_serve_step
+    cfg = dataclasses.replace(resolve("h2o-danube-3-4b", smoke=True),
+                              num_layers=8)
+    slots, max_seq = 8, 512
+    step = build_serve_step(cfg, max_seq=max_seq, slots=slots)
+    params = jax.eval_shape(lambda: init_model(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(step.init_state)
+    tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
+    compiled = step.decode.lower(params, tok, state).compile()
+    k = state.cache["k"]
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < k.size * k.dtype.itemsize
+    text = compiled.as_text()
+    # outputs (logits, cache k, cache v, length); arguments (params, token,
+    # cache k, cache v, length)
+    first = len(jax.tree.leaves(params)) + 1
+    alias = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}",
+                            text.split("\n", 1)[0]))
+    assert (alias.get("1"), alias.get("2")) == (str(first), str(first + 1))
+    stack = re.escape("[" + ",".join(map(str, k.shape)) + "]")
+    copies = [line for line in text.splitlines()
+              if re.search(r"= \w+" + stack + r"\S* copy\(", line)]
+    assert not copies, copies

@@ -53,15 +53,23 @@ def test_compiled_programs_carry_the_model_scopes(engine, program):
 
 
 def test_decode_cache_select_sits_inside_kv_write(engine):
-    """Every select over one layer's K or V cache ([slots, max_seq, kv
-    heads, head dim]) is the decode's cache write, scoped ``kv_write``."""
+    """The decode's cache write is scoped ``kv_write``: every op that writes
+    the stacked K or V cache ([layers, slots, max_seq, kv heads, head
+    dim]) in place, and every select of the rows it writes ([slots, kv
+    heads, head dim]: the new row, or a slot past max_seq's row kept)."""
     cfg = engine.cfg
-    row = f"[{SLOTS},{MAX_SEQ},{cfg.num_kv_heads},{cfg.hd()}]"
-    selects = [n for line, n in _op_names(_compiled_text(engine, "decode"))
-               if re.search(r"= \w+" + re.escape(row) + r"\S* select\(",
-                            line)]
-    assert selects
-    assert all("/attn/kv_write/" in n for n in selects), selects
+    K, hd = cfg.num_kv_heads, cfg.hd()
+    stack = re.escape(f"[{cfg.num_layers},{SLOTS},{MAX_SEQ},{K},{hd}]")
+    rows = re.escape(f"[{SLOTS},{K},{hd}]")
+    names = _op_names(_compiled_text(engine, "decode"))
+    writes = [n for line, n in names
+              if (m := re.search(r"= \w+" + stack + r"\S* ([\w-]+)\(", line))
+              and m.group(1) not in ("parameter", "get-tuple-element")]
+    selects = [n for line, n in names
+               if re.search(r"= \w+" + rows + r"\S* select\(", line)]
+    assert writes and selects
+    assert all("/attn/kv_write/" in n for n in writes + selects), \
+        writes + selects
 
 
 def test_admit_stamps_arrival_before_the_prefill(engine, monkeypatch):

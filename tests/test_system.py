@@ -1,6 +1,7 @@
 """End-to-end behaviour: dry-run artifacts are complete and healthy, the
 roofline inputs exist, and the production mesh constructors behave."""
 import json
+import math
 import pathlib
 
 import pytest
@@ -91,3 +92,54 @@ def test_production_mesh_requires_512_devices():
     else:
         with pytest.raises(Exception):
             make_production_mesh(multi_pod=True)
+
+
+_SEQ_SHARDED_DECODE = """
+import sys
+import numpy as np
+import jax
+from repro.launch import dryrun
+from repro.configs import resolve, ShapeConfig
+cfg = resolve(sys.argv[1], smoke=True)
+mesh = jax.sharding.Mesh(np.array(jax.devices()[:8]).reshape(2, 4),
+                         ("data", "model"))
+shape = ShapeConfig("decode_seq_sharded", int(sys.argv[2]),
+                    int(sys.argv[3]), "decode")
+lowered, _ = dryrun.lower_cell(cfg, shape, mesh)
+print(lowered.compile().as_text())
+"""
+
+
+def test_dryrun_decode_with_sequence_sharded_cache_keeps_the_select():
+    """A decode lowered as the dry-run lowers it, with each layer's cache
+    sharded (batch over "data", S over "model", 8 CPU devices): the cache
+    write is the one-hot select over each chip's own layer slice, and no
+    all-gather brings the cache together (a write at a traced position
+    would make GSPMD gather it)."""
+    import os
+    import re
+    import subprocess
+    import sys
+    arch, S, B = "llama3.2-3b", 256, 8
+    cfg = resolve(arch, smoke=True)
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+           "PYTHONPATH": os.pathsep.join(
+               p for p in (src, os.environ.get("PYTHONPATH")) if p)}
+    proc = subprocess.run(
+        [sys.executable, "-c", _SEQ_SHARDED_DECODE, arch, str(S), str(B)],
+        capture_output=True, text=True, timeout=600, env=env)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    text = proc.stdout
+    local = f"[{B // 2},{S // 4},{cfg.num_kv_heads},{cfg.hd()}]"
+    selects = [line for line in text.splitlines()
+               if re.search(r"= \w+" + re.escape(local) + r"\S* select\(",
+                            line) and "/attn/kv_write/" in line]
+    assert selects, "no one-hot select over a chip's layer slice"
+    slice_elems = (B // 2) * (S // 4) * cfg.num_kv_heads * cfg.hd()
+    gathered = [m.group(1) for m in re.finditer(
+        r"= \w+\[([\d,]*)\]\S* all-gather\(", text)
+        if math.prod(int(d) for d in m.group(1).split(",") if d)
+        >= slice_elems]
+    assert not gathered, gathered
