@@ -29,8 +29,9 @@ Kinds:
               final-norm "extras" pseudo-layer (params AND moments) live
               in the bucket-major (L, B, p, s) master layouts of
               ``repro.models.blockstack.shard_stack``; only the family
-              spec's replicated_keys (the hybrid weight-shared attention
-              block) stay replicated.
+              spec's replicated_keys (the hybrid's weight-shared blocks
+              with their per-application adapters and projections) stay
+              replicated.
 
 The concrete checkpoint canonicalization for each kind lives in
 :mod:`repro.checkpoint.layouts`.

@@ -42,8 +42,14 @@ class ModelConfig:
     ssm_chunk: int = 64
     ssm_conv_width: int = 4
     ssm_groups: int = 1
-    # hybrid (Zamba2): one *shared* attention block applied every k layers
-    hybrid_attn_every: int = 0
+    # hybrid (Zamba2, arXiv:2411.15242): a Mamba2 mixer at every layer; at
+    # each layer of hybrid_layer_ids one of num_mem_blocks weight-shared
+    # attention+MLP blocks (in turn) reads concat(h, token embeddings), and
+    # its output, through that application's own rank-adapter_rank MLP
+    # adapter and d x d projection, joins the Mamba layer's input
+    hybrid_layer_ids: tuple = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
     # encoder-decoder (Whisper): frontend stubbed to frame embeddings
     encoder_layers: int = 0
     encoder_seq: int = 0
@@ -103,10 +109,14 @@ class ModelConfig:
             di, S, Hs = self.d_inner(), self.ssm_state, self.ssm_heads()
             G = self.ssm_groups
             per_m = (d * (2 * di + 2 * G * S + Hs)
-                     + self.ssm_conv_width * (di + 2 * G * S)
+                     + (self.ssm_conv_width + 1) * (di + 2 * G * S)
                      + 3 * Hs + di + di * d + d)
             total += L * per_m
-            total += per_attn + per_mlp + per_norms       # one shared block
+            # the shared blocks read concat(h, embeddings): 2d wide in
+            n_app, r = len(self.hybrid_layer_ids), self.adapter_rank
+            shared = (3 * 2 * d * H * hd + H * hd * d + 3 * d + 3 * d * f)
+            total += self.num_mem_blocks * shared
+            total += n_app * (d * r + r * 2 * f + d * d)  # per application
         else:
             total += L * (per_attn + per_mlp + per_norms)
             if self.encoder_layers:

@@ -447,9 +447,9 @@ def _build_zero3(comm, ctx: StepContext):
     """ZeRO-3/FSDP step, family-agnostic: the family's registered
     BlockSpec (models/blockstack.py) splits the params into the scanned
     layer stack, the "extras" pseudo-layer (embed/final_norm/...) and the
-    replicated leftovers (the hybrid shared attention block only).  The
-    stack stays sharded 1/p per chip (shard_stack layout) and is
-    re-gathered LAYER BY LAYER inside the forward scan via
+    replicated leftovers (only the hybrid's shared blocks, adapters and
+    projections).  The stack stays sharded 1/p per chip (shard_stack
+    layout) and is re-gathered LAYER BY LAYER inside the forward scan via
     comm.prefetch_allgather — the pipelined AG(lane)→AG(node) with a
     one-layer prefetch buffer so layer i+1's gather overlaps layer i's
     compute (run.fsdp_prefetch: 0 = cost-model block count, >0 =
@@ -791,8 +791,8 @@ def zero3_opt_init(cfg: ModelConfig, params, n: int, N: int,
     """Split optimizer state for the lane_zero3 step: flat sharded fp32
     moments in the (L, B, p, s) master layouts for the layer stack AND
     the extras pseudo-layer, ordinary AdamW tree state for the family's
-    replicated keys (empty for most families; the hybrid weight-shared
-    attention block).  The B resolution MUST match the step's
+    replicated keys (empty for most families; the hybrid's shared blocks,
+    adapters and projections).  The B resolution MUST match the step's
     (resolve_prefetch_blocks is deterministic, so the default 0 agrees;
     pass the same run.fsdp_prefetch override on both sides).  ``ep=True``
     adds the "experts" entry: natural-shape fp32 moments for the expert

@@ -92,7 +92,7 @@ def _gqa_scores(qb, kb):
 
 def attention_xla(q, k, v, *, causal: bool, window: int = 0,
                   q_offset: int = 0, block_q: int = 512, block_k: int = 512,
-                  save_memory: bool = True):
+                  save_memory: bool = True, scale: float | None = None):
     """Flash-style blocked attention, pure lax (runs/lowers anywhere).
 
     q: (B, Tq, H, hd); k, v: (B, Tk, K, hd); H = K·G.
@@ -102,13 +102,15 @@ def attention_xla(q, k, v, *, causal: bool, window: int = 0,
     ``save_memory`` checkpoints each k-step so the backward recomputes the
     score block instead of storing nk fp32 (bq,bk) tiles per layer — the
     flash-attention trade, expressed at the lax level (the Pallas kernel
-    does the same natively on TPU).
+    does the same natively on TPU).  ``scale`` multiplies the scores
+    (default ``hd ** -0.5``).
     Returns (B, Tq, H, hd) in q.dtype.
     """
     B, Tq, H, hd = q.shape
     Tk, K = k.shape[1], k.shape[2]
     G = H // K
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     qs = (q * scale).reshape(B, Tq, K, G, hd)
 
     if window and window < Tk:
@@ -197,8 +199,10 @@ def _attention_banded(qs, k, v, *, window: int, causal: bool, q_offset: int,
 # decode (single new token against a cache)
 # ---------------------------------------------------------------------------
 
-def decode_attention(q, kcache, vcache, cache_len, *, window: int = 0):
-    """q: (B, 1, H, hd); caches: (B, S, K, hd); cache_len: current length.
+def decode_attention(q, kcache, vcache, cache_len, *, window: int = 0,
+                     scale: float | None = None):
+    """q: (B, 1, H, hd); caches: (B, S, K, hd); cache_len: current length;
+    ``scale`` multiplies the scores (default ``hd ** -0.5``).
 
     The cache's S dim may be sharded over the model axis — the max/sum
     reductions below are over that dim, which GSPMD lowers to local partial
@@ -208,7 +212,8 @@ def decode_attention(q, kcache, vcache, cache_len, *, window: int = 0):
     B, _, H, hd = q.shape
     S, K = kcache.shape[1], kcache.shape[2]
     G = H // K
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     qh = (q[:, 0] * scale).reshape(B, K, G, hd)
     s = jnp.einsum("bkgd,bskd->bkgs", qh, kcache,
                    preferred_element_type=jnp.float32)

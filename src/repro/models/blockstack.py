@@ -416,20 +416,23 @@ class BlockSpec:
 
     stack_key        top-level params key of the scanned (L, ...) stack.
     replicated_keys  top-level keys that stay replicated on every chip
-                     (the Zamba2 weight-shared attention block: it is
-                     applied ``groups`` times per forward, so sharding it
-                     would re-gather the same weights repeatedly); their
-                     gradients sync through the bucketed ``lane`` path.
+                     (Zamba2's ``shared_attn``: its weight-shared blocks
+                     run at several layers per forward, so sharding them
+                     would re-gather the same weights repeatedly; the
+                     per-application adapters and projections stay
+                     beside them); their gradients sync through the
+                     bucketed ``lane`` path.
                      Every OTHER key (embed, final_norm, vis_proj,
                      encoder, ...) becomes the "extras" pseudo-layer:
                      1/p-sharded like one more stack row, gathered once
                      per step.
     make_body        ``make_body(cfg, params, *, positions, enc_out,
-                     remat) -> body(h, layer_params, layer_idx) ->
+                     remat, x0) -> body(h, layer_params, layer_idx) ->
                      (h', aux)`` — the per-layer scan body
                      :func:`scan_stack` drives (``params`` carries the
                      replicated/extras trees the body may close over,
-                     e.g. the hybrid shared block).
+                     e.g. the hybrid shared blocks; ``x0`` is the token
+                     embeddings, which the hybrid's shared blocks read).
     needs_extra_embeds
                      the family's forward requires an extra_embeds input
                      (vlm patches / audio frames) the training driver

@@ -149,7 +149,9 @@ def init_mlp(key, cfg: ModelConfig) -> dict:
 
 
 def _act(name: str):
+    """``gelu`` is the tanh form; ``gelu_exact`` the erf one."""
     return {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
+            "gelu_exact": functools.partial(jax.nn.gelu, approximate=False),
             "relu": jax.nn.relu}[name]
 
 

@@ -161,11 +161,13 @@ def ssd_decode_step(state, x_t, dt_t, A, B_t, C_t):
 # full block
 # ---------------------------------------------------------------------------
 
-def _conv1d(xBC, w, b, conv_state=None):
+def _conv1d(xBC, w, b, conv_state=None, length=None):
     """Depthwise causal conv, width W.  xBC: (B,T,C); w: (W,C).
 
     If conv_state (B, W-1, C) is given, it prefixes the sequence
-    (decode/prefill continuation) and the updated state is returned.
+    (decode/prefill continuation) and the updated state is returned: the
+    last W-1 inputs, or with ``length`` (B,) the W-1 inputs before each
+    row's true end (a right-padded prompt).
     """
     W = w.shape[0]
     if conv_state is None:
@@ -175,49 +177,90 @@ def _conv1d(xBC, w, b, conv_state=None):
     full = jnp.concatenate([pad, xBC], axis=1)            # (B, T+W-1, C)
     out = sum(full[:, i:i + xBC.shape[1]] * w[i][None, None]
               for i in range(W))
-    new_state = full[:, -(W - 1):] if W > 1 else pad
+    if W == 1:
+        new_state = pad
+    elif length is None:
+        new_state = full[:, -(W - 1):]
+    else:
+        new_state = jax.vmap(lambda f, n: lax.dynamic_slice_in_dim(
+            f, n, W - 1, axis=0))(full, length)
     return out + b[None, None], new_state
 
 
-def mamba2_block(p: dict, x, cfg: ModelConfig, *, state=None):
-    """x: (B, T, d) → (B, T, d).  state: None (train) or serve-state dict."""
+def gated_norm(p: dict, y, z, groups: int, eps: float):
+    """Mamba2's gated RMSNorm: ``y * silu(z)`` normalised over each of
+    ``groups`` equal channel groups (the published ``RMSNormGated`` with
+    ``group_size = d_inner // ngroups``), then scaled."""
+    g = y * jax.nn.silu(z)
+    if groups == 1:
+        return rmsnorm(p, g, eps)
+    shape = g.shape
+    gf = g.astype(jnp.float32).reshape(*shape[:-1], groups, -1)
+    var = jnp.mean(jnp.square(gf), axis=-1, keepdims=True)
+    out = (gf * jax.lax.rsqrt(var + eps)).reshape(shape)
+    return (out * p["scale"].astype(jnp.float32)).astype(g.dtype)
+
+
+def mamba2_block(p: dict, x, cfg: ModelConfig, *, state=None, length=None):
+    """x: (B, T, d) → (B, T, d).  state: None (train) or serve-state dict.
+
+    ``length`` (B,) marks a prefill's true prompt lengths where ``x`` is
+    right-padded to a bucket: past it ``dt`` and the SSM input are 0, so
+    ``exp(dt·A) = 1`` and the state passes through the padding unchanged,
+    and the conv state is read at the true end.  Named scopes: ``mamba``
+    (the whole mixer), ``ssd`` inside it (the chunked scan, or the decode's
+    state step)."""
+    with jax.named_scope("mamba"):
+        return _mamba2(p, x, cfg, state, length)
+
+
+def _mamba2(p: dict, x, cfg: ModelConfig, state, length):
     Bsz, T, _ = x.shape
     H, P = cfg.ssm_heads(), cfg.ssm_head_dim
     G, S = cfg.ssm_groups, cfg.ssm_state
+    masked = state is not None and T > 1 and length is not None
+    conv_len = length if masked else None
     z = x @ p["w_z"]
     xs = x @ p["w_x"]
     Bp = x @ p["w_B"]
     Cp = x @ p["w_C"]
     dt = x @ p["w_dt"]
     cs = state if state is not None else {}
-    xs, new_cx = _conv1d(xs, p["conv_x_w"], p["conv_x_b"], cs.get("conv_x"))
-    Bp, new_cB = _conv1d(Bp, p["conv_B_w"], p["conv_B_b"], cs.get("conv_B"))
-    Cp, new_cC = _conv1d(Cp, p["conv_C_w"], p["conv_C_b"], cs.get("conv_C"))
+    xs, new_cx = _conv1d(xs, p["conv_x_w"], p["conv_x_b"], cs.get("conv_x"),
+                         conv_len)
+    Bp, new_cB = _conv1d(Bp, p["conv_B_w"], p["conv_B_b"], cs.get("conv_B"),
+                         conv_len)
+    Cp, new_cC = _conv1d(Cp, p["conv_C_w"], p["conv_C_b"], cs.get("conv_C"),
+                         conv_len)
     xs, Bp, Cp = jax.nn.silu(xs), jax.nn.silu(Bp), jax.nn.silu(Cp)
     xs = xs.reshape(Bsz, T, H, P)
     Bp = Bp.reshape(Bsz, T, G, S)
     Cp = Cp.reshape(Bsz, T, G, S)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"][None, None])
     A = -jnp.exp(p["A_log"])
+    if masked:
+        valid = jnp.arange(T)[None, :] < length[:, None]        # (B, T)
+        dt = jnp.where(valid[..., None], dt, 0.0)
+        xs = jnp.where(valid[..., None, None], xs, jnp.zeros((), xs.dtype))
 
-    if state is None:
-        y, _ = ssd_chunked(xs, dt, A, Bp, Cp, chunk=cfg.ssm_chunk)
-        new_state = None
-    elif T == 1:
-        y1, new_ssm = ssd_decode_step(state["ssm"], xs[:, 0], dt[:, 0],
-                                      A, Bp[:, 0], Cp[:, 0])
-        y = y1[:, None]
-        new_state = {"conv_x": new_cx, "conv_B": new_cB, "conv_C": new_cC,
-                     "ssm": new_ssm}
-    else:  # prefill with state capture
-        y, new_ssm = ssd_chunked(xs, dt, A, Bp, Cp, chunk=cfg.ssm_chunk,
-                                 init_state=state["ssm"])
+    with jax.named_scope("ssd"):
+        if state is None:
+            y, _ = ssd_chunked(xs, dt, A, Bp, Cp, chunk=cfg.ssm_chunk)
+            new_state = None
+        elif T == 1:
+            y1, new_ssm = ssd_decode_step(state["ssm"], xs[:, 0], dt[:, 0],
+                                          A, Bp[:, 0], Cp[:, 0])
+            y = y1[:, None]
+        else:  # prefill with state capture
+            y, new_ssm = ssd_chunked(xs, dt, A, Bp, Cp, chunk=cfg.ssm_chunk,
+                                     init_state=state["ssm"])
+    if state is not None:
         new_state = {"conv_x": new_cx, "conv_B": new_cB, "conv_C": new_cC,
                      "ssm": new_ssm}
 
     y = y + xs * p["D"][None, None, :, None]          # fp32 D promotes…
     y = y.reshape(Bsz, T, cfg.d_inner()).astype(x.dtype)  # …cast back
-    y = rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
+    y = gated_norm(p["norm"], y, z, G, cfg.norm_eps)
     return y @ p["out_proj"], new_state
 
 

@@ -4,9 +4,11 @@ Families
   dense  — llama-style pre-norm blocks (GQA attn + [Sw]GLU MLP), scanned
   moe    — same skeleton with the MLP swapped for the capacity MoE
   ssm    — Mamba2 blocks only (attention-free)
-  hybrid — Mamba2 backbone + ONE weight-shared attention block applied
-           every `hybrid_attn_every` layers (Zamba2; weight sharing is the
-           published design, simplification: standard residual insertion)
+  hybrid — Zamba2 (arXiv:2411.15242): a Mamba2 layer at every depth; at
+           each of ``hybrid_layer_ids`` one of ``num_mem_blocks``
+           weight-shared attention+MLP blocks, in turn, reads
+           concat(h, token embeddings) and joins the Mamba layer's input
+           through that application's own adapter and projection
   vlm    — dense backbone consuming [projected patch embeds | token embeds]
   audio  — Whisper backbone: bidirectional encoder over stub frame
            embeddings + causal decoder with cross-attention
@@ -21,7 +23,9 @@ them into the cache in place after the scan (``_kv_write``).
 Named scopes (``jax.named_scope``; metadata only, the compiled program is
 unchanged) mark each op's layer in its HLO ``op_name``: ``attn`` (norm,
 QKV, attention, output projection), ``kv_write`` inside it (the cache
-write), ``mlp`` (the feed-forward block).
+write), ``mlp`` (the feed-forward block; in the hybrid's shared block also
+its adapter and the application's projection), and from ``models/ssm.py``
+``mamba`` (the whole Mamba2 mixer) with ``ssd`` inside it.
 """
 from __future__ import annotations
 
@@ -93,6 +97,39 @@ def _init_mamba_layer(key, cfg: ModelConfig) -> dict:
             "mamba": S.init_mamba2(k1, cfg)}
 
 
+def _init_hybrid_shared(key, cfg: ModelConfig) -> dict:
+    """Zamba2's replicated leftovers: ``mem``, the ``num_mem_blocks``
+    shared blocks (attention over concat(h, embeddings), 2·d wide in, and
+    a gate/up MLP), and ``apps``, per application its MLP adapter and its
+    d x d output projection."""
+    d, H, hd, f, r = (cfg.d_model, cfg.num_heads, cfg.hd(), cfg.d_ff,
+                      cfg.adapter_rank)
+    dt = L._dtype(cfg)
+    kb, ka = jax.random.split(key)
+
+    def block(k):
+        k = jax.random.split(k, 6)
+        return {"ln_in": _init_norm(cfg, 2 * d),
+                "attn": {"wq": L.dense_init(k[0], (2 * d, H * hd), dt),
+                         "wk": L.dense_init(k[1], (2 * d, H * hd), dt),
+                         "wv": L.dense_init(k[2], (2 * d, H * hd), dt),
+                         "wo": L.dense_init(k[3], (H * hd, d), dt)},
+                "ln_ff": _init_norm(cfg, d),
+                "mlp": {"w_gate_up": L.dense_init(k[4], (d, 2 * f), dt),
+                        "w_down": L.dense_init(k[5], (f, d), dt)}}
+
+    def app(k):
+        k = jax.random.split(k, 3)
+        return {"adapter_in": L.dense_init(k[0], (d, r), dt),
+                "adapter_out": L.dense_init(k[1], (r, 2 * f), dt),
+                "linear": L.dense_init(k[2], (d, d), dt)}
+
+    return {"mem": [block(k) for k in
+                    jax.random.split(kb, cfg.num_mem_blocks)],
+            "apps": [app(k) for k in
+                     jax.random.split(ka, len(cfg.hybrid_layer_ids))]}
+
+
 def _stacked(init_fn, key, n: int):
     keys = jax.random.split(key, n)
     return jax.vmap(init_fn)(keys)
@@ -111,7 +148,7 @@ def init_model(key, cfg: ModelConfig) -> dict:
     elif cfg.family == "hybrid":
         params["blocks"] = _stacked(lambda k: _init_mamba_layer(k, cfg),
                                     ks[1], cfg.num_layers)
-        params["shared_attn"] = _init_attn_layer(ks[2], cfg)
+        params["shared_attn"] = _init_hybrid_shared(ks[2], cfg)
     elif cfg.family == "audio":
         params["blocks"] = _stacked(
             lambda k: _init_attn_layer(k, cfg, cross=True), ks[1],
@@ -279,7 +316,7 @@ def model_forward(params, cfg: ModelConfig, tokens, *, extra_embeds=None,
         # prefetch/blocking/regather layer scan (models/blockstack.py)
         spec = block_stack_spec(cfg)
         body = spec.make_body(cfg, params, positions=positions,
-                              enc_out=enc_out, remat=remat)
+                              enc_out=enc_out, remat=remat, x0=h)
         h, aux_ys = scan_stack(params["blocks"], h, body)
         aux_total = jnp.sum(aux_ys)
 
@@ -309,49 +346,77 @@ def model_forward(params, cfg: ModelConfig, tokens, *, extra_embeds=None,
     return logits, aux_total
 
 
-def _hybrid_split(cfg: ModelConfig):
-    every = cfg.hybrid_attn_every
-    groups = cfg.num_layers // every
-    tail = cfg.num_layers - groups * every
-    return groups, every, tail
+# ---------------------------------------------------------------------------
+# hybrid (Zamba2): one layer body for the forward, lane_zero3 and serving
+# ---------------------------------------------------------------------------
+
+def _hybrid_apps(cfg: ModelConfig):
+    """(L,) int32: 0 at a Mamba-only layer, j + 1 at layer
+    ``hybrid_layer_ids[j]``, the j-th application of a shared block."""
+    ids = list(cfg.hybrid_layer_ids)
+    if ids != sorted(set(ids)) or (ids and not 0 <= ids[0] <= ids[-1]
+                                   < cfg.num_layers):
+        raise ValueError(f"{cfg.name}: hybrid_layer_ids {ids} must be "
+                         f"increasing layer indices below {cfg.num_layers}")
+    table = [0] * cfg.num_layers
+    for j, i in enumerate(ids):
+        table[i] = j + 1
+    return jnp.asarray(table, jnp.int32)
 
 
-def _tree_first(tree, n):
-    return jax.tree.map(lambda a: a[:n], tree)
+def _hybrid_scale(cfg: ModelConfig) -> float:
+    """Zamba2's attention scaling, ``(head_dim / 2) ** -0.5`` as in the
+    published modeling code: its heads are twice as wide as the hidden
+    size gives per head, the input being concat(h, embeddings)."""
+    return (cfg.hd() / 2) ** -0.5
 
 
-def _tree_rest(tree, n):
-    return jax.tree.map(lambda a: a[n:], tree)
+def _shared_block(cfg: ModelConfig, shared, j: int, h, x0, positions,
+                  attend):
+    """Zamba2's shared transformer block at application ``j``: block
+    ``j % num_mem_blocks`` (A, B, A, ...) over ``u = RMSNorm(concat(h,
+    x0))``, attention, ``a = RMSNorm(attention output)``, and the MLP
+    ``down(gelu(g) * v)`` with ``[g, v] = W_gu a + B_j (A_j a)`` (the
+    application's own adapter), with no residual; then the application's
+    projection.  Returns ``t`` (B, T, d), which joins the Mamba layer's
+    input, and what ``attend(j, q, k, v) -> (o, extra)`` (the path's
+    attention) returns beside the attention output."""
+    blk = shared["mem"][j % len(shared["mem"])]
+    app = shared["apps"][j]
+    with jax.named_scope("attn"):
+        u = _norm(cfg, blk["ln_in"], jnp.concatenate([h, x0], axis=-1))
+        q, k, v = A.qkv(blk["attn"], u, cfg, positions=positions, rope=True)
+        o, extra = attend(j, q, k, v)
+        o = o.reshape(*o.shape[:2], -1) @ blk["attn"]["wo"]
+    with jax.named_scope("mlp"):
+        a = _norm(cfg, blk["ln_ff"], o)
+        gu = a @ blk["mlp"]["w_gate_up"] \
+            + (a @ app["adapter_in"]) @ app["adapter_out"]
+        g, up = jnp.split(gu, 2, axis=-1)
+        m = (L._act(cfg.act)(g) * up) @ blk["mlp"]["w_down"]
+        return m @ app["linear"], extra
+
+
+def _hybrid_layer(cfg: ModelConfig, lp, h, t=None, *, state=None,
+                  length=None):
+    """Zamba2's layer: ``h + Mamba2(RMSNorm(h + t))``, ``t`` the shared
+    block's output at a hybrid layer (:func:`_shared_block`) and None
+    elsewhere; the residual is the ``h`` before ``t``.  ``state``/
+    ``length``: the layer's serving state and a prefill's true lengths
+    (:func:`repro.models.ssm.mamba2_block`).  Returns ``(h, new state)``."""
+    hn = _norm(cfg, lp["ln1"], h if t is None else h + t)
+    out, st = S.mamba2_block(lp["mamba"], hn, cfg, state=state,
+                             length=length)
+    return h + out, st
 
 
 def _hybrid_forward(params, cfg: ModelConfig, h, positions, remat):
-    """Zamba2: every `every` Mamba2 layers, apply the shared attn block."""
-    groups, every, tail = _hybrid_split(cfg)
-    shared = params["shared_attn"]
-    head = _tree_first(params["blocks"], groups * every)
-    head = jax.tree.map(
-        lambda a: a.reshape(groups, every, *a.shape[1:]), head)
-
-    def mamba_body(h, lp):
-        h, _ = _mamba_block(lp, h, cfg)
-        return _pin(h), None
-
-    # nested remat: without it the whole 6-layer group's SSD internals
-    # (the (nc,Q,Q,H) decay tensors) stay live during the group backward
-    mamba_body = _maybe_remat(mamba_body, remat)
-
-    def group_body(h, gp):
-        h = _attn_noncache(shared, h, cfg, causal=True, positions=positions,
-                           window=cfg.sliding_window)
-        h, _ = _ffn(shared, h, cfg)
-        h, _ = lax.scan(mamba_body, h, gp)
-        return _pin(h), None
-
-    group_body = _maybe_remat(group_body, remat)
-    h, _ = lax.scan(group_body, h, head)
-    if tail:
-        tail_p = _tree_rest(params["blocks"], groups * every)
-        h, _ = lax.scan(mamba_body, h, tail_p)
+    """The replicated forward: the lane_zero3 per-layer body
+    (:func:`_hybrid_stack_body`) scanned over the layer stack."""
+    body = _hybrid_stack_body(cfg, params, positions=positions, enc_out=None,
+                              remat=remat, x0=h)
+    h, _ = lax.scan(lambda h, x: body(h, *x), h,
+                    (params["blocks"], jnp.arange(cfg.num_layers)))
     return h
 
 
@@ -361,7 +426,7 @@ def _hybrid_forward(params, cfg: ModelConfig, h, positions, remat):
 # models/blockstack.py, the zero3 step resolves specs via block_stack_spec)
 # ---------------------------------------------------------------------------
 
-def _scanned_stack_body(cfg, params, *, positions, enc_out, remat):
+def _scanned_stack_body(cfg, params, *, positions, enc_out, remat, x0=None):
     """Per-layer body of the scanned attention families (dense/vlm/moe/
     audio): identical math to the replicated layer scan.
 
@@ -383,7 +448,7 @@ def _scanned_stack_body(cfg, params, *, positions, enc_out, remat):
     return _maybe_remat(body, remat)
 
 
-def _ssm_stack_body(cfg, params, *, positions, enc_out, remat):
+def _ssm_stack_body(cfg, params, *, positions, enc_out, remat, x0=None):
     """Mamba2 SSD scan bodies as the sharded layer unit."""
     def body(h, lp, i):
         h, _ = _mamba_block(lp, h, cfg)
@@ -391,30 +456,29 @@ def _ssm_stack_body(cfg, params, *, positions, enc_out, remat):
     return _maybe_remat(body, remat)
 
 
-def _hybrid_stack_body(cfg, params, *, positions, enc_out, remat):
-    """Zamba2 grouped layout as a flat per-layer scan: the weight-SHARED
-    attention block (replicated — it runs ``groups`` times per forward,
-    so sharding it would re-gather the same bytes repeatedly) fires
-    before Mamba2 layer i exactly when i opens a group; the tail layers
-    past ``groups·every`` never see it — the same schedule as the
-    replicated ``_hybrid_forward``, without its nested group scan.  The
-    remat cell is the per-layer body only, and the prefetch gather stays
-    OUTSIDE it, so a backward recompute re-runs the block math but never
-    the gather (pinned by the gather-count HLO case)."""
-    groups, every, tail = _hybrid_split(cfg)
+def _hybrid_stack_body(cfg, params, *, positions, enc_out, remat, x0=None):
+    """Zamba2 as a flat per-layer scan (:func:`_hybrid_layer`), ``x0`` the
+    token embeddings the shared blocks read.  The shared blocks, adapters
+    and projections (``params["shared_attn"]``) stay replicated: a block
+    runs at several layers, so sharding it would re-gather the same bytes.
+    The remat cell is the per-layer body only, and the prefetch gather
+    stays OUTSIDE it, so a backward recompute re-runs the block math but
+    never the gather (pinned by the gather-count HLO case)."""
     shared = params["shared_attn"]
+    scale = _hybrid_scale(cfg)
 
-    def shared_block(h):
-        h = _attn_noncache(shared, h, cfg, causal=True, positions=positions,
-                           window=cfg.sliding_window)
-        h, _ = _ffn(shared, h, cfg)
-        return h
+    def attend(j, q, k, v):
+        return A.attention_xla(q, k, v, causal=True,
+                               window=cfg.sliding_window, scale=scale), None
 
     def body(h, lp, i):
-        at_group_start = jnp.logical_and(i % every == 0,
-                                         i < groups * every)
-        h = lax.cond(at_group_start, shared_block, lambda hh: hh, h)
-        h, _ = _mamba_block(lp, h, cfg)
+        # the layer's application, or none: lax.switch on its traced index
+        apps = [lambda j=j: _shared_block(cfg, shared, j, h, x0, positions,
+                                          attend)[0]
+                for j in range(len(cfg.hybrid_layer_ids))]
+        t = lax.switch(_hybrid_apps(cfg)[i], [lambda: jnp.zeros_like(h)]
+                       + apps)
+        h, _ = _hybrid_layer(cfg, lp, h, t)
         return _pin(h), jnp.zeros((), jnp.float32)
     return _maybe_remat(body, remat)
 
@@ -448,9 +512,10 @@ def _block_stack_ssm(cfg: ModelConfig) -> BlockSpec:
 
 @register_block_stack("hybrid")
 def _block_stack_hybrid(cfg: ModelConfig) -> BlockSpec:
-    """Mamba2 backbone sharded 1/p; the weight-shared attention block
-    stays replicated (``replicated_keys``) and its gradient syncs through
-    the bucketed lane path."""
+    """Mamba2 backbone sharded 1/p; the weight-shared blocks with the
+    per-application adapters and projections stay replicated
+    (``replicated_keys``) and their gradients sync through the bucketed
+    lane path."""
     return BlockSpec(family="hybrid", make_body=_hybrid_stack_body,
                      replicated_keys=("shared_attn",))
 
@@ -520,12 +585,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
         return jax.tree.map(
             lambda a: jnp.zeros((Lr, *a.shape), a.dtype), st)
     if cfg.family == "hybrid":
-        groups, every, tail = _hybrid_split(cfg)
         st = S.init_mamba_state(cfg, batch)
         return {
             "mamba": jax.tree.map(
                 lambda a: jnp.zeros((Lr, *a.shape), a.dtype), st),
-            "attn": kv(groups),
+            "attn": kv(len(cfg.hybrid_layer_ids)),   # one per application
         }
     raise ValueError(cfg.family)
 
@@ -580,6 +644,34 @@ def _kv_write(cache, rows, length):
     return out
 
 
+def _decode_attend(q, k, v, lc, length, *, last, window: int,
+                   scale: Optional[float] = None):
+    """The decode's attention of q (B,1,H,hd) against one layer's cache
+    ``lc`` {"k","v"} (B,S,K,hd) with the new k/v row selected in at
+    ``length``.  Returns the output and the new rows {"k","v"} (B,K,hd)
+    for :func:`_kv_write` (where a slot is at or past S, its row of
+    ``last``, :func:`_kv_last_rows`)."""
+    Smax = lc["k"].shape[1]
+    # one-hot select at per-row `length` (GSPMD-safe on a sharded S
+    # dim; pure select — an arithmetic blend promoted the cache to
+    # fp32 on the CPU backend)
+    with jax.named_scope("kv_write"):
+        hot = (jnp.arange(Smax)[None, :] == length[:, None])  # (B,S)
+        newk = pin_kv(jnp.where(hot[..., None, None],
+                                k.astype(lc["k"].dtype), lc["k"]))
+        newv = pin_kv(jnp.where(hot[..., None, None],
+                                v.astype(lc["v"].dtype), lc["v"]))
+        newc = {"k": k[:, 0].astype(lc["k"].dtype),
+                "v": v[:, 0].astype(lc["v"].dtype)}
+        if last is not None:
+            past = (length >= Smax)[:, None, None]
+            newc = {n: jnp.where(past, last[n], r)
+                    for n, r in newc.items()}
+    o = A.decode_attention(q, newk, newv, length + 1, window=window,
+                           scale=scale)
+    return o, newc
+
+
 def _attn_cached(lp, h, cfg: ModelConfig, lc, length, *, prefill: bool,
                  enc_kv=None, last=None):
     """Attention with cache read/write.  h: (B,T,d); lc: {"k","v"} (B,S,K,hd).
@@ -592,7 +684,6 @@ def _attn_cached(lp, h, cfg: ModelConfig, lc, length, *, prefill: bool,
              layer's :func:`_kv_last_rows`) for :func:`_kv_write`.
     """
     Bz, T, _ = h.shape
-    Smax = lc["k"].shape[1]
     positions = (jnp.arange(T)[None] if prefill else length[:, None])
     with jax.named_scope("attn"):
         hn = _norm(cfg, lp["ln1"], h)
@@ -607,23 +698,8 @@ def _attn_cached(lp, h, cfg: ModelConfig, lc, length, *, prefill: bool,
             o = A.attention_xla(q, k, v, causal=True,
                                 window=cfg.sliding_window)
         else:
-            # one-hot select at per-row `length` (GSPMD-safe on a sharded S
-            # dim; pure select — an arithmetic blend promoted the cache to
-            # fp32 on the CPU backend)
-            with jax.named_scope("kv_write"):
-                hot = (jnp.arange(Smax)[None, :] == length[:, None])  # (B,S)
-                newk = pin_kv(jnp.where(hot[..., None, None],
-                                        k.astype(lc["k"].dtype), lc["k"]))
-                newv = pin_kv(jnp.where(hot[..., None, None],
-                                        v.astype(lc["v"].dtype), lc["v"]))
-                newc = {"k": k[:, 0].astype(lc["k"].dtype),
-                        "v": v[:, 0].astype(lc["v"].dtype)}
-                if last is not None:
-                    past = (length >= Smax)[:, None, None]
-                    newc = {n: jnp.where(past, last[n], r)
-                            for n, r in newc.items()}
-            o = A.decode_attention(q, newk, newv, length + 1,
-                                   window=cfg.sliding_window)
+            o, newc = _decode_attend(q, k, v, lc, length, last=last,
+                                     window=cfg.sliding_window)
         o = o.reshape(Bz, T, -1) @ lp["attn"]["wo"]
         h = h + o
         if enc_kv is not None and "xattn" in lp:
@@ -666,10 +742,11 @@ def prefill(params, cfg: ModelConfig, tokens, cache, *, extra_embeds=None,
     vlm vision tokens) instead of the bucket's last position — the seed
     engine conditioned the first generated token on trailing pad — and
     ``state.length`` is ``prefix + true_len``, so decode overwrites the
-    pad region progressively and attention never reads past it.  Only
-    meaningful for attention caches; the recurrent families (ssm/hybrid)
-    fold every consumed token into their state, so their callers must
-    prefill at the exact prompt length (the engine does).
+    pad region progressively and attention never reads past it.  For
+    the recurrent families (ssm/hybrid) it also keeps the padding out of
+    the recurrence: past it ``dt`` and the SSM input are 0, so the state
+    passes through unchanged, and the conv state is read at the true end
+    (:func:`repro.models.ssm.mamba2_block`).
 
     ``params["blocks"]`` may be a :class:`ShardedStack` (zero3 hosting):
     the cached layer scan then runs through ``scan_stack_cached`` with the
@@ -689,6 +766,8 @@ def prefill(params, cfg: ModelConfig, tokens, cache, *, extra_embeds=None,
         h = _embed_inputs(params, cfg, tokens, extra_embeds)
     Bz, T, _ = h.shape
     length0 = jnp.zeros((Bz,), jnp.int32)
+    tl = None if true_len is None else jnp.broadcast_to(
+        jnp.asarray(true_len, jnp.int32), (Bz,))
 
     if sharded:
         if cfg.family == "audio":
@@ -710,15 +789,17 @@ def prefill(params, cfg: ModelConfig, tokens, cache, *, extra_embeds=None,
         elif cfg.family == "ssm":
             def body(h, lp, lc):
                 hn = _norm(cfg, lp["ln1"], h)
-                out, st = S.mamba2_block(lp["mamba"], hn, cfg, state=lc)
+                out, st = S.mamba2_block(lp["mamba"], hn, cfg, state=lc,
+                                         length=tl)
                 return h + out, st
             h, newcache = scan_stack_cached(params["blocks"], h, cache,
                                             body)
         else:
             raise ValueError(
                 f"family {cfg.family!r} cannot serve from a ShardedStack "
-                f"(the hybrid grouped attention cache does not fit the "
-                f"flat layer scan); host it replicated")
+                f"(its replicated shared blocks and per-application cache "
+                f"are not in the flat cached layer scan); host it "
+                f"replicated")
     elif cfg.family in _SCANNED_FAMILIES:
         xs = (params["blocks"], cache) if enc_kv is None else \
              (params["blocks"], cache, enc_kv)
@@ -735,12 +816,13 @@ def prefill(params, cfg: ModelConfig, tokens, cache, *, extra_embeds=None,
         def body(h, lpc):
             lp, lc = lpc
             hn = _norm(cfg, lp["ln1"], h)
-            out, st = S.mamba2_block(lp["mamba"], hn, cfg, state=lc)
+            out, st = S.mamba2_block(lp["mamba"], hn, cfg, state=lc,
+                                     length=tl)
             return h + out, st
         h, newcache = lax.scan(body, h, (params["blocks"], cache))
     elif cfg.family == "hybrid":
         h, newcache = _hybrid_cached(params, cfg, h, cache, length0,
-                                     prefill=True)
+                                     prefill=True, true_len=tl)
     else:
         raise ValueError(cfg.family)
 
@@ -750,7 +832,6 @@ def prefill(params, cfg: ModelConfig, tokens, cache, *, extra_embeds=None,
         h_last = h[:, -1:]
         length = jnp.full((Bz,), T, jnp.int32)
     else:
-        tl = jnp.broadcast_to(jnp.asarray(true_len, jnp.int32), (Bz,))
         h_last = _select_row(h, prefix + tl - 1)
         length = prefix + tl
     logits = L.unembed(params["embed"], h_last)
@@ -797,8 +878,9 @@ def decode_step(params, cfg: ModelConfig, token, state: ServeState):
         if cfg.family != "ssm":
             raise ValueError(
                 f"family {cfg.family!r} cannot serve from a ShardedStack "
-                f"(the hybrid grouped attention cache does not fit the "
-                f"flat layer scan); host it replicated")
+                f"(its replicated shared blocks and per-application cache "
+                f"are not in the flat cached layer scan); host it "
+                f"replicated")
 
         def body(h, lp, lc):
             hn = _norm(cfg, lp["ln1"], h)
@@ -826,52 +908,77 @@ def decode_step(params, cfg: ModelConfig, token, state: ServeState):
     return logits, new_state
 
 
-def _hybrid_cached(params, cfg: ModelConfig, h, cache, length, *, prefill):
-    """Zamba2 cached pass: the shared attention block, then the group's
-    Mamba2 layers.  Prefill threads the grouped attention cache through
-    xs/ys; decode emits its new rows and writes them after the group
-    scan, as :func:`decode_step`."""
-    groups, every, tail = _hybrid_split(cfg)
-    shared = params["shared_attn"]
-    head = _tree_first(params["blocks"], groups * every)
-    head = jax.tree.map(lambda a: a.reshape(groups, every, *a.shape[1:]), head)
-    mcache_head = _tree_first(cache["mamba"], groups * every)
-    mcache_head = jax.tree.map(
-        lambda a: a.reshape(groups, every, *a.shape[1:]), mcache_head)
-    attn = cache["attn"]
-    last = None if prefill else _kv_last_rows(attn)
+def _hybrid_cached(params, cfg: ModelConfig, h, cache, length, *, prefill,
+                   true_len=None):
+    """Zamba2 serving pass, in program order: at each hybrid layer its
+    application of a shared block, then a scan over that layer and the
+    Mamba-only layers up to the next one, each layer's weights and state
+    read by index from the stacks and its new state written back in place
+    (slices of the stacks taken outside the loop were copied whole on the
+    chip).  Each application reads its own (B, S, K, hd) slice of the
+    (applications, B, S, K, hd) cache at a static index, never inside a
+    loop or a conditional (either made the chip's compiler copy the whole
+    stack), and gives its K/V rows: the prefill's every prompt position,
+    written at position 0 after the pass; the decode's new row per slot,
+    attended with it selected in as :func:`decode_step` does and written
+    in place through :func:`_kv_write`.  ``true_len``: the prefill's true
+    prompt lengths."""
+    x0, kv, shared = h, cache["attn"], params["shared_attn"]
+    scale = _hybrid_scale(cfg)
+    if prefill:
+        positions = jnp.arange(h.shape[1])[None]
 
-    def mamba_body(h, lpc):
-        lp, lc = lpc
-        hn = _norm(cfg, lp["ln1"], h)
-        out, st = S.mamba2_block(lp["mamba"], hn, cfg, state=lc)
-        return h + out, st
-
-    def group_body(h, gx):
-        gp, gmc, g, glast = gx
-        gac = {n: lax.dynamic_index_in_dim(a, g, 0, keepdims=False)
-               for n, a in attn.items()}
-        h, newac = _attn_cached(shared, h, cfg, gac, length,
-                                prefill=prefill, last=glast)
-        h, newmc = lax.scan(mamba_body, h, (gp, gmc))
-        return h, (newmc, newac)
-
-    h, (new_mc_head, new_ac) = lax.scan(
-        group_body, h, (head, mcache_head, jnp.arange(groups), last))
-    if not prefill:
-        with jax.named_scope("attn"), jax.named_scope("kv_write"):
-            new_ac = _kv_write(attn, new_ac, length)
-    new_mc_head = jax.tree.map(
-        lambda a: a.reshape(groups * every, *a.shape[2:]), new_mc_head)
-    if tail:
-        tail_p = _tree_rest(params["blocks"], groups * every)
-        tail_c = _tree_rest(cache["mamba"], groups * every)
-        h, new_mc_tail = lax.scan(mamba_body, h, (tail_p, tail_c))
-        new_mc = jax.tree.map(lambda a, b: jnp.concatenate([a, b], 0),
-                              new_mc_head, new_mc_tail)
+        def attend(j, q, k, v):
+            o = A.attention_xla(q, k, v, causal=True,
+                                window=cfg.sliding_window, scale=scale)
+            return o, {"k": k.astype(kv["k"].dtype),
+                       "v": v.astype(kv["v"].dtype)}
     else:
-        new_mc = new_mc_head
-    return h, {"mamba": new_mc, "attn": new_ac}
+        positions = length[:, None]
+        last = _kv_last_rows(kv)
+
+        def attend(j, q, k, v):
+            lc = {n: a[j] for n, a in kv.items()}
+            lj = None if last is None else {n: a[j] for n, a in last.items()}
+            return _decode_attend(q, k, v, lc, length, last=lj,
+                                  window=cfg.sliding_window, scale=scale)
+
+    def run(h, t, mc, lo, hi):
+        """Layers lo..hi-1, each read by index from the stacks of weights
+        and states, its new state written back in place; ``t`` joins the
+        first one's input."""
+        def body(carry, i):
+            h, t, mc = carry
+            at = lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+            h, st = _hybrid_layer(cfg, jax.tree.map(at, params["blocks"]), h,
+                                  t, state=jax.tree.map(at, mc),
+                                  length=true_len)
+            mc = jax.tree.map(lambda a, s: lax.dynamic_update_index_in_dim(
+                a, s.astype(a.dtype), i, 0), mc, st)
+            return (h, jnp.zeros_like(t), mc), None
+        (h, _, mc), _ = lax.scan(body, (h, t, mc), jnp.arange(lo, hi))
+        return h, mc
+
+    # segment k runs layers bounds[k]..bounds[k+1]-1; from the second on
+    # it opens with a hybrid layer, whose application comes first
+    bounds = (0, *cfg.hybrid_layer_ids, cfg.num_layers)
+    mc, rows, t = cache["mamba"], [], jnp.zeros_like(h)
+    for k in range(len(bounds) - 1):
+        if k:
+            t, r = _shared_block(cfg, shared, k - 1, h, x0, positions,
+                                 attend)
+            rows.append(r)
+        if bounds[k + 1] > bounds[k]:
+            h, mc = run(h, t, mc, bounds[k], bounds[k + 1])
+    with jax.named_scope("attn"), jax.named_scope("kv_write"):
+        rows = {n: jnp.stack([r[n] for r in rows]) for n in kv}
+        if prefill:
+            new_kv = {n: lax.dynamic_update_slice_in_dim(a, rows[n], 0,
+                                                         axis=2)
+                      for n, a in kv.items()}
+        else:
+            new_kv = _kv_write(kv, rows, length)
+    return h, {"mamba": mc, "attn": new_kv}
 
 
 # ---------------------------------------------------------------------------

@@ -29,12 +29,13 @@ Correctness contracts pinned by tests/test_serve.py:
   * termination: eos / max_new_tokens / max_seq fire exactly once per
     request and are recorded in ``finish_reason``.
 
-Recurrent families (ssm/hybrid) prefill at the EXACT prompt length —
-their state folds in every consumed token, so bucket padding would
-contaminate the recurrence; attention families keep bucketed prompts
-(bounded compile count) and rely on ``prefill(..., true_len=...)`` to
-read logits at the last true position while the padded tail stays dead
-behind the length mask.
+Every family prefills bucketed prompts (a bounded compile count) and
+relies on ``prefill(..., true_len=...)``: logits are read at the last
+true position; the attention cache's padded tail stays dead behind the
+length mask, and the recurrent families (ssm/hybrid) keep the padding out
+of their state (``dt = 0`` and a zero input past the true length, the
+conv state read at the true end).  ``pad_positions`` counts the padded
+prompt positions the engine has prefilled.
 """
 from __future__ import annotations
 
@@ -81,11 +82,6 @@ def _install_gc_span() -> None:
     """Put a ``host.gc`` span round every collection (once a process)."""
     if _gc_span not in gc.callbacks:
         gc.callbacks.append(_gc_span)
-
-
-# families whose serving state is a recurrence over every consumed token
-# (pad tokens would corrupt it) — prefilled at exact prompt length
-_RECURRENT_FAMILIES = ("ssm", "hybrid")
 
 
 @dataclasses.dataclass
@@ -146,6 +142,9 @@ class ContinuousBatcher:
     step      inject a prebuilt ServeStep to share jit caches across
               engines (the equivalence tests run batched and sequential
               engines over ONE step).
+
+    ``pad_positions`` counts the prompt positions past the true length
+    that admission has prefilled (the bucket's padding).
     """
 
     def __init__(self, params, cfg, *, slots: int, max_seq: int,
@@ -181,6 +180,7 @@ class ContinuousBatcher:
         self._last_tok = np.zeros((self.slots,), np.int32)
         self._prefix = cfg.vision_tokens if cfg.family == "vlm" else 0
         self._sample_fn = None
+        self.pad_positions = 0
         if sampler is not None and not sampler.greedy:
             import jax
             self._sample_fn = jax.jit(
@@ -218,13 +218,11 @@ class ContinuousBatcher:
 
     def _bucket_for(self, L: int) -> int:
         """Prompt pad width: smallest registered bucket >= L (falling
-        back to the prompt length itself past the largest bucket), exact
-        L for the recurrent families.  Never below L — long prompts
-        select a LARGER bucket instead of truncating.  Admission has
-        already proven ``prefix + L + max_new_tokens <= max_seq``, so
-        the capacity clamp can never push the bucket under L."""
-        if self.cfg.family in _RECURRENT_FAMILIES:
-            return L
+        back to the prompt length itself past the largest bucket).  Never
+        below L — long prompts select a LARGER bucket instead of
+        truncating.  Admission has already proven ``prefix + L +
+        max_new_tokens <= max_seq``, so the capacity clamp can never push
+        the bucket under L."""
         cap = self.max_seq - self._prefix
         for b in self.buckets:
             if b >= L:
@@ -272,6 +270,7 @@ class ContinuousBatcher:
                     f"prefill bucket {b} shorter than prompt length {L}")
             toks = np.zeros((1, b), np.int32)
             toks[0, :L] = prompt          # whole prompt, never sliced
+            self.pad_positions += b - L
             with TraceAnnotation("serve.dispatch"):
                 logits, st1 = self.step.prefill(
                     self.hosted, jnp.asarray(toks), L,

@@ -5,8 +5,9 @@ hosting flavor is one ``@register_impl("serve_step", ...)`` cell —
 
   replicated   every chip holds full weights; prefill/decode are plain
                jits (the single-host baseline, and the only hosting the
-               hybrid family supports — its grouped attention cache does
-               not fit the flat layer scan).
+               hybrid family supports — its replicated shared blocks
+               and per-application cache are not in the flat cached
+               layer scan).
   lane_zero3   1/p weight hosting: the family's BlockSpec splits the
                params exactly like training (models/blockstack.py), the
                (L, B, p, s) masters stay sharded, and every prefill/
@@ -153,7 +154,7 @@ def _init_serve_state(cfg: ModelConfig, batch: int, max_seq: int):
 
 
 # every stacked cache leaf — kv (L,B,S,K,hd), the stacked mamba states,
-# the hybrid grouped kv (groups,B,S,K,hd), enc_kv (L,B,Te,K,hd) — keeps
+# the hybrid per-application kv (applications,B,S,K,hd), enc_kv (L,B,Te,K,hd) — keeps
 # batch at axis 1; length is the single axis-0 exception
 _BATCH_AXIS = 1
 
@@ -220,8 +221,9 @@ def _serve_zero3(ctx: ServeContext) -> ServeStep:
     if cfg.family == "hybrid":
         raise ValueError(
             "the hybrid family cannot serve from 1/p-sharded weights "
-            "(its grouped attention cache does not fit the flat cached "
-            "layer scan); use hosting='replicated'")
+            "(its replicated shared blocks and per-application cache "
+            "are not in the flat cached layer scan); use "
+            "hosting='replicated'")
     mesh = ctx.mesh
     ba = batch_axes(mesh)
     topo = LaneTopology(node_axes=ba[1:], lane_axis=ba[0])

@@ -40,7 +40,11 @@ def test_exact_published_configs():
     m = resolve("mamba2-780m")
     assert (m.ssm_state, m.num_layers, m.d_model) == (128, 48, 1536)
     z = resolve("zamba2-7b")
-    assert (z.hybrid_attn_every, z.ssm_state) == (6, 64)
+    assert z.hybrid_layer_ids == (6, 11, 17, 23, 29, 35, 41, 47, 53, 59,
+                                  65, 71, 77)
+    assert (z.num_layers, z.num_mem_blocks, z.adapter_rank, z.hd(),
+            z.ssm_state, z.ssm_groups, z.ssm_chunk) == (81, 2, 128, 224,
+                                                        64, 2, 256)
     gr = resolve("granite-34b")
     assert (gr.num_kv_heads, gr.num_layers) == (1, 88)
     w = resolve("whisper-large-v3")

@@ -99,3 +99,37 @@ def test_decode_writes_the_cache_in_place_at_h2o_danube_width(one_chip):
                          compiled.as_text()))
     assert ops <= {"parameter", "get-tuple-element",
                    "dynamic-update-slice"}, ops
+
+
+def test_hybrid_decode_keeps_its_caches_in_place_at_zamba2_width(one_chip):
+    """The replicated hybrid decode at the benchmark's cut (24 layers,
+    applications at 6, 11, 17, 23) and 16 slots x 2048: no op but an
+    in-place dynamic-update-slice makes the whole (applications, slots,
+    S, K, hd) K or V stack, and the temporaries stay under 2.5e9 B.  A
+    layer loop that picks the application by a conditional copied the
+    whole K/V stack (5.7e9 B of temporaries), and slices of the weight
+    stacks taken outside the loops copied them (3.4e9 B); what is left is
+    the f32 SSM state relaid in and out of the loops (2 x 0.705e9 B)."""
+    import dataclasses
+    from repro.models import init_model
+    from repro.serve import build_serve_step
+    cfg = dataclasses.replace(resolve("zamba2-7b"), num_layers=24,
+                              hybrid_layer_ids=(6, 11, 17, 23))
+    slots, max_seq = 16, 2048
+    step = build_serve_step(cfg, max_seq=max_seq, slots=slots)
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: init_model(jax.random.PRNGKey(0), cfg)))
+    state = on_chip(jax.eval_shape(step.init_state))
+    tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    compiled = step.decode.lower(params, tok, state).compile()
+    k = state.cache["attn"]["k"]
+    assert k.shape == (4, 16, 2048, 32, 224)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    stack = re.escape("[" + ",".join(map(str, k.shape)) + "]")
+    ops = set(re.findall(r"= bf16" + stack + r"\S* ([\w-]+)\(",
+                         compiled.as_text()))
+    assert ops <= {"parameter", "get-tuple-element",
+                   "dynamic-update-slice"}, ops

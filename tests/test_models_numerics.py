@@ -178,34 +178,35 @@ def _onehot_attn(lp, h, cfg, lc, length, ekv):
 
 
 def _onehot_hybrid(params, cfg, h, cache, length):
-    from repro.models import ssm as S
+    """The hybrid decode layer by layer, each application's whole cache
+    selected into by one-hot and returned whole."""
+    from repro.models import attention as A
     from repro.models import transformer as T
-    groups, every, tail = T._hybrid_split(cfg)
-    n = groups * every
-    grouped = lambda t: jax.tree.map(
-        lambda a: a[:n].reshape(groups, every, *a.shape[1:]), t)
+    kv, x0, mc = cache["attn"], h, cache["mamba"]
 
-    def mamba(h, x):
-        lp, lc = x
-        out, st = S.mamba2_block(lp["mamba"], T._norm(cfg, lp["ln1"], h),
-                                 cfg, state=lc)
-        return h + out, st
+    def attend(j, q, k, v):
+        lc = {n: a[j] for n, a in kv.items()}
+        hot = (jnp.arange(lc["k"].shape[1])[None, :]
+               == length[:, None])[..., None, None]
+        new = {n: jnp.where(hot, x.astype(lc[n].dtype), lc[n])
+               for n, x in (("k", k), ("v", v))}
+        o = A.decode_attention(q, new["k"], new["v"], length + 1,
+                               window=cfg.sliding_window,
+                               scale=T._hybrid_scale(cfg))
+        return o, new
 
-    def group(h, x):
-        gp, gmc, gac = x
-        h, ac = _onehot_attn(params["shared_attn"], h, cfg, gac, length,
-                             None)
-        h, mc = jax.lax.scan(mamba, h, (gp, gmc))
-        return h, (mc, ac)
-
-    h, (mc, ac) = jax.lax.scan(group, h, (grouped(params["blocks"]),
-                                          grouped(cache["mamba"]),
-                                          cache["attn"]))
-    mc = jax.tree.map(lambda a: a.reshape(n, *a.shape[2:]), mc)
-    if tail:
-        h, mt = jax.lax.scan(mamba, h, jax.tree.map(
-            lambda a: a[n:], (params["blocks"], cache["mamba"])))
-        mc = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), mc, mt)
+    new = []
+    for i in range(cfg.num_layers):
+        t = jnp.zeros_like(h)
+        if i in cfg.hybrid_layer_ids:
+            t, n = T._shared_block(cfg, params["shared_attn"],
+                                   cfg.hybrid_layer_ids.index(i), h, x0,
+                                   length[:, None], attend)
+            new.append(n)
+        lp, st = jax.tree.map(lambda a: a[i], (params["blocks"], mc))
+        h, st = T._hybrid_layer(cfg, lp, h, t, state=st)
+        mc = jax.tree.map(lambda a, s: a.at[i].set(s), mc, st)
+    ac = {n: jnp.stack([a[n] for a in new]) for n in kv}
     return h, {"mamba": mc, "attn": ac}
 
 

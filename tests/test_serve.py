@@ -363,16 +363,29 @@ def test_admit_over_budget_raises():
 
 
 def test_recurrent_families_prefill_exact_length():
-    """ssm/hybrid fold every consumed token into their state, so their
-    bucket IS the prompt length (pad tokens would contaminate the
-    recurrence); attention families keep power-of-two-ish buckets."""
-    ssm = resolve(FAMILY_ARCHS["ssm"], smoke=True)
+    """ssm/hybrid take bucketed prompts like the attention families, and
+    a padded prefill serves exactly what the exact-length prefill does:
+    the padding stays out of the recurrence.  The engine counts the
+    padded positions."""
     dense = resolve(FAMILY_ARCHS["dense"], smoke=True)
-    e_ssm = ContinuousBatcher(_params(ssm), ssm, slots=1, max_seq=MAX_SEQ)
     e_dense = ContinuousBatcher(_params(dense), dense, slots=1,
                                 max_seq=MAX_SEQ)
-    assert e_ssm._bucket_for(13) == 13
     assert e_dense._bucket_for(13) == 32
+    for fam in ("ssm", "hybrid"):
+        cfg = resolve(FAMILY_ARCHS[fam], smoke=True)
+        params = _params(cfg)
+        padded = ContinuousBatcher(params, cfg, slots=1, max_seq=MAX_SEQ)
+        exact = ContinuousBatcher(params, cfg, slots=1, max_seq=MAX_SEQ,
+                                  buckets=(13,))
+        assert padded._bucket_for(13) == 32
+        outs = []
+        for eng in (padded, exact):
+            req = Request(0, (np.arange(13, dtype=np.int32) * 7) % 200 + 1,
+                          max_new_tokens=6)
+            eng.run([req])
+            outs.append(req.out)
+        assert outs[0] == outs[1], fam
+        assert (padded.pad_positions, exact.pad_positions) == (32 - 13, 0)
 
 
 # ---------------------------------------------------------------------------
