@@ -37,6 +37,7 @@ from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.configs import resolve, all_archs, cells, SHAPES, RunConfig
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import init_model, init_cache
+from repro.models.ssm import ssm_heads_axis
 from repro.models.transformer import ServeState
 from repro.optim import AdamWConfig, adamw_init
 from repro.launch.mesh import make_production_mesh, batch_axes, mesh_sizes
@@ -224,7 +225,7 @@ def _lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh,
                     spec[-1] = "model"
             elif ps.endswith("ssm"):
                 spec[1] = bspec
-                spec[2] = "model"
+                spec[ssm_heads_axis(cfg)] = "model"
             return P(*spec)
         specs = jax.tree_util.tree_map_with_path(rule, cshapes)
         return sh.sanitize_specs(cshapes, specs, mesh)

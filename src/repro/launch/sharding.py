@@ -1,4 +1,4 @@
-"""Sharding rules: parameter PartitionSpecs, input specs, cache specs.
+"""Sharding rules: parameter PartitionSpecs and input specs.
 
 Conventions (DESIGN.md §3):
   batch axes   ("pod","data")  — token batch, serve batch
@@ -122,41 +122,6 @@ def opt_pspecs(param_specs: Any) -> Any:
     """AdamW moments mirror params; count is replicated."""
     return {"m": param_specs, "v": param_specs,
             "count": P()}
-
-
-def cache_pspecs(cache_shapes: Any, cfg: ModelConfig, mesh, *,
-                 seq_shard: bool = True) -> Any:
-    """Serve-cache specs.  KV caches (L,B,S,K,hd): S shards over "model"
-    (decode reads it with the distributed-LSE pattern); Mamba states shard
-    their head/channel dims over "model"."""
-    ba = batch_axes(mesh)
-
-    def rule(path, leaf):
-        ps = _path_str(path)
-        nd = len(leaf.shape)
-        if ps in ("k", "v") or ps.endswith("/k") or ps.endswith("/v"):
-            # (L?, B, S, K, hd) — enc_kv has no layer lead handled by nd
-            spec = [None] * nd
-            spec[nd - 4] = ba            # batch dim
-            if seq_shard:
-                spec[nd - 3] = "model"
-            return P(*spec)
-        if "conv" in ps:                 # (L,B,W-1,C): C over model for x
-            spec = [None] * nd
-            spec[1] = ba
-            if ps.endswith("conv_x"):
-                spec[-1] = "model"
-            return P(*spec)
-        if ps.endswith("ssm"):           # (L,B,H,P,S): heads over model
-            spec = [None] * nd
-            spec[1] = ba
-            spec[2] = "model"
-            return P(*spec)
-        if ps.endswith("length"):
-            return P(ba)
-        return P()
-
-    return jax.tree_util.tree_map_with_path(rule, cache_shapes)
 
 
 def sanitize_specs(shapes_tree: Any, spec_tree: Any, mesh) -> Any:

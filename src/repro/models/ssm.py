@@ -138,21 +138,54 @@ def ssd_chunked(x, dt, A, B, C, *, chunk: int, init_state=None):
     return y.astype(x.dtype), final_state
 
 
-def ssd_decode_step(state, x_t, dt_t, A, B_t, C_t):
+def ssm_heads_last(cfg: ModelConfig) -> bool:
+    """Whether the f32 SSM state is stored heads-last, ``(B, P, S, H)``,
+    rather than ``(B, H, P, S)``.  The chip tiles an array's minor axis
+    over 128 lanes, so the minor axis is whichever of heads and state size
+    fills a row better (``n / roundup(n, 128)``), the state size on a tie:
+    Zamba2-7B's 112 heads (0.875) beat its state of 64 (0.5), Mamba2-780m's
+    state of 128 (1.0) beats its 48 heads (0.375).  The state keeps that
+    one layout from the cache through every layer loop: a decode whose
+    loops took another layout than its argument relaid the whole stack on
+    the way in and out."""
+    fill = lambda n: n / (-(-n // 128) * 128)
+    return fill(cfg.ssm_heads()) > fill(cfg.ssm_state)
+
+
+def ssm_heads_axis(cfg: ModelConfig) -> int:
+    """The SSM state's heads axis, counted from the end: the same for one
+    layer's ``(B, ...)`` state and the stacked ``(L, B, ...)`` cache."""
+    return -1 if ssm_heads_last(cfg) else -3
+
+
+def ssd_decode_step(state, x_t, dt_t, A, B_t, C_t, *, heads_last=False):
     """One-token SSD update.
 
-    state: (b,H,P,S); x_t: (b,H,P); dt_t: (b,H); B_t/C_t: (b,G,S).
-    Returns (y_t (b,H,P), new_state).
+    state: (b,H,P,S), or (b,P,S,H) when ``heads_last``; x_t: (b,H,P);
+    dt_t: (b,H); B_t/C_t: (b,G,S).  Returns (y_t (b,H,P), new_state).
+    Heads-last, the update is a broadcast product and the read-out a sum
+    over S, so the state keeps its order through the step.
     """
     H = x_t.shape[1]
     G = B_t.shape[1]
     rep = H // G
+    dt = dt_t.astype(jnp.float32)
+    da = dt * A[None, :]                                   # (b,H)
+    if heads_last:
+        Bs = jnp.repeat(jnp.swapaxes(B_t.astype(jnp.float32), 1, 2), rep,
+                        axis=2)                             # (b,S,H)
+        Cs = jnp.repeat(jnp.swapaxes(C_t.astype(jnp.float32), 1, 2), rep,
+                        axis=2)
+        dtx = jnp.swapaxes(dt[:, :, None] * x_t.astype(jnp.float32), 1, 2)
+        new_state = (state * jnp.exp(da)[:, None, None, :]
+                     + dtx[:, :, None, :] * Bs[:, None])     # (b,P,S,H)
+        y = jnp.swapaxes(jnp.sum(Cs[:, None] * new_state, axis=2), 1, 2)
+        return y.astype(x_t.dtype), new_state
     Bh = jnp.repeat(B_t.astype(jnp.float32), rep, axis=1)  # (b,H,S)
     Ch = jnp.repeat(C_t.astype(jnp.float32), rep, axis=1)
-    da = dt_t.astype(jnp.float32) * A[None, :]             # (b,H)
     new_state = (state * jnp.exp(da)[:, :, None, None]
-                 + jnp.einsum("bh,bhs,bhp->bhps", dt_t.astype(jnp.float32),
-                              Bh, x_t.astype(jnp.float32)))
+                 + jnp.einsum("bh,bhs,bhp->bhps", dt, Bh,
+                              x_t.astype(jnp.float32)))
     y = jnp.einsum("bhs,bhps->bhp", Ch, new_state)
     return y.astype(x_t.dtype), new_state
 
@@ -243,17 +276,23 @@ def _mamba2(p: dict, x, cfg: ModelConfig, state, length):
         dt = jnp.where(valid[..., None], dt, 0.0)
         xs = jnp.where(valid[..., None, None], xs, jnp.zeros((), xs.dtype))
 
+    heads_last = ssm_heads_last(cfg)
     with jax.named_scope("ssd"):
         if state is None:
             y, _ = ssd_chunked(xs, dt, A, Bp, Cp, chunk=cfg.ssm_chunk)
             new_state = None
         elif T == 1:
             y1, new_ssm = ssd_decode_step(state["ssm"], xs[:, 0], dt[:, 0],
-                                          A, Bp[:, 0], Cp[:, 0])
+                                          A, Bp[:, 0], Cp[:, 0],
+                                          heads_last=heads_last)
             y = y1[:, None]
-        else:  # prefill with state capture
-            y, new_ssm = ssd_chunked(xs, dt, A, Bp, Cp, chunk=cfg.ssm_chunk,
-                                     init_state=state["ssm"])
+        else:  # prefill with state capture, the scan's state (b,H,P,S)
+            s0 = state["ssm"]
+            y, new_ssm = ssd_chunked(
+                xs, dt, A, Bp, Cp, chunk=cfg.ssm_chunk,
+                init_state=jnp.moveaxis(s0, -1, 1) if heads_last else s0)
+            if heads_last:
+                new_ssm = jnp.moveaxis(new_ssm, 1, -1)
     if state is not None:
         new_state = {"conv_x": new_cx, "conv_B": new_cB, "conv_C": new_cC,
                      "ssm": new_ssm}
@@ -271,5 +310,6 @@ def init_mamba_state(cfg: ModelConfig, batch: int, dtype=jnp.float32) -> dict:
         "conv_x": jnp.zeros((batch, W - 1, di), dtype),
         "conv_B": jnp.zeros((batch, W - 1, G * S), dtype),
         "conv_C": jnp.zeros((batch, W - 1, G * S), dtype),
-        "ssm": jnp.zeros((batch, H, P, S), jnp.float32),
+        "ssm": jnp.zeros((batch, P, S, H) if ssm_heads_last(cfg)
+                         else (batch, H, P, S), jnp.float32),
     }

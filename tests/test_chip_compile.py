@@ -101,15 +101,11 @@ def test_decode_writes_the_cache_in_place_at_h2o_danube_width(one_chip):
                    "dynamic-update-slice"}, ops
 
 
-def test_hybrid_decode_keeps_its_caches_in_place_at_zamba2_width(one_chip):
+@pytest.fixture(scope="module")
+def hybrid_decode(one_chip):
     """The replicated hybrid decode at the benchmark's cut (24 layers,
-    applications at 6, 11, 17, 23) and 16 slots x 2048: no op but an
-    in-place dynamic-update-slice makes the whole (applications, slots,
-    S, K, hd) K or V stack, and the temporaries stay under 2.5e9 B.  A
-    layer loop that picks the application by a conditional copied the
-    whole K/V stack (5.7e9 B of temporaries), and slices of the weight
-    stacks taken outside the loops copied them (3.4e9 B); what is left is
-    the f32 SSM state relaid in and out of the loops (2 x 0.705e9 B)."""
+    applications at 6, 11, 17, 23) and 16 slots x 2048, compiled for one
+    described v5e: (its serve state's shapes, the compiled program)."""
     import dataclasses
     from repro.models import init_model
     from repro.serve import build_serve_step
@@ -124,7 +120,19 @@ def test_hybrid_decode_keeps_its_caches_in_place_at_zamba2_width(one_chip):
         lambda: init_model(jax.random.PRNGKey(0), cfg)))
     state = on_chip(jax.eval_shape(step.init_state))
     tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
-    compiled = step.decode.lower(params, tok, state).compile()
+    return state, step.decode.lower(params, tok, state).compile()
+
+
+def test_hybrid_decode_keeps_its_caches_in_place_at_zamba2_width(
+        hybrid_decode):
+    """No op but an in-place dynamic-update-slice makes the whole
+    (applications, slots, S, K, hd) K or V stack, and the temporaries stay
+    under 2.5e9 B.  A layer loop that picks the application by a
+    conditional copied the whole K/V stack (5.7e9 B of temporaries),
+    slices of the weight stacks taken outside the loops copied them
+    (3.4e9 B), and the f32 SSM state stored with S minor was relaid in
+    and out of the loops (2 x 0.705e9 B); what is left is 0.42e9 B."""
+    state, compiled = hybrid_decode
     k = state.cache["attn"]["k"]
     assert k.shape == (4, 16, 2048, 32, 224)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
@@ -133,3 +141,25 @@ def test_hybrid_decode_keeps_its_caches_in_place_at_zamba2_width(one_chip):
                          compiled.as_text()))
     assert ops <= {"parameter", "get-tuple-element",
                    "dynamic-update-slice"}, ops
+
+
+def test_hybrid_decode_keeps_the_ssm_state_in_one_layout_at_zamba2_width(
+        hybrid_decode):
+    """The f32 SSM state is stored heads-last, (layers, slots, P, S, H):
+    112 heads fill 112 of the chip's 128 lanes where S = 64 fills half.
+    Stored (.., H, P, S), the argument took the chip's heads-minor layout
+    while the middle layer loops ran S-minor, and two copies of the whole
+    stack (about 3.4 ms each on the chip) converted it between them.  Now
+    every value shaped like the stack has one layout, no copy makes it,
+    and the decode's temporaries stay under 0.6e9 B (0.42e9 measured,
+    1.95e9 with the copies)."""
+    state, compiled = hybrid_decode
+    ssm = state.cache["mamba"]["ssm"]
+    assert ssm.shape == (24, 16, 64, 64, 112)
+    stack = re.escape("[" + ",".join(map(str, ssm.shape)) + "]")
+    made = re.findall(r"= f32" + stack + r"(\{[^}]*\})? ([\w-]+)\(",
+                      compiled.as_text())
+    assert made
+    assert len({layout for layout, _ in made}) == 1, made
+    assert "copy" not in {op for _, op in made}, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9

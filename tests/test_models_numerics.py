@@ -6,7 +6,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.attention import attention_xla, decode_attention
-from repro.models.ssm import ssd_chunked, ssd_decode_step
+from repro.models.ssm import (ssd_chunked, ssd_decode_step, ssm_heads_axis,
+                              ssm_heads_last)
 from repro.models import moe as M
 from repro.kernels import ref as kref
 from repro.configs import resolve
@@ -101,21 +102,56 @@ def test_ssd_chunked_grad_finite_under_strong_decay():
         assert np.isfinite(np.asarray(g)).all()
 
 
-def test_ssd_decode_continues_prefill():
-    """prefill(T) state + decode(1) == prefill(T+1) last output."""
+@pytest.mark.parametrize("heads_last", [False, True],
+                         ids=["state_last", "heads_last"])
+def test_ssd_decode_continues_prefill(heads_last):
+    """prefill(T) state + decode(1) == prefill(T+1) last output, with the
+    state stored (b,H,P,S) or heads-last (b,P,S,H); two groups, so the
+    group broadcast is in the step.  Both orders give the same new state."""
     rng = np.random.default_rng(3)
-    b, T, H, P, S, G = 1, 32, 2, 8, 16, 1
+    b, T, H, P, S, G = 1, 32, 4, 8, 16, 2
     mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     x = mk(b, T + 1, H, P)
     dt = jnp.asarray(rng.uniform(0.01, 0.2, size=(b, T + 1, H)), jnp.float32)
     A = jnp.asarray(-rng.uniform(0.2, 2.0, size=(H,)), jnp.float32)
     Bm, Cm = mk(b, T + 1, G, S), mk(b, T + 1, G, S)
-    y_all, _ = ssd_chunked(x, dt, A, Bm, Cm, chunk=16)
+    y_all, want_state = ssd_chunked(x, dt, A, Bm, Cm, chunk=16)
     _, state = ssd_chunked(x[:, :T], dt[:, :T], A, Bm[:, :T], Cm[:, :T],
                            chunk=16)
-    y1, _ = ssd_decode_step(state, x[:, T], dt[:, T], A, Bm[:, T], Cm[:, T])
+    if heads_last:
+        state = jnp.moveaxis(state, 1, -1)
+    y1, new_state = ssd_decode_step(state, x[:, T], dt[:, T], A, Bm[:, T],
+                                    Cm[:, T], heads_last=heads_last)
+    if heads_last:
+        new_state = jnp.moveaxis(new_state, -1, 1)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y_all[:, T]),
                                rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(new_state), np.asarray(want_state),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("arch,state,heads_last", [
+    ("zamba2-7b", None, True),     # H 112 fills 0.875 of 128 lanes, S 64 0.5
+    ("mamba2-780m", None, False),  # S 128 fills 1.0, H 48 0.375
+    ("zamba2-7b", 112, False),     # a tie, H 112 against S 112: state last
+])
+def test_ssm_state_layout_follows_the_lane_fill(arch, state, heads_last):
+    """At published widths the state's minor axis is whichever of heads
+    and state size fills a 128-lane row better, the state on a tie; the
+    serve cache is stored in that order, and ``ssm_heads_axis`` (which the
+    dry run shards over "model") finds its heads."""
+    import dataclasses
+    from repro.models import init_cache
+    cfg = resolve(arch)
+    if state is not None:
+        cfg = dataclasses.replace(cfg, ssm_state=state)
+    assert ssm_heads_last(cfg) is heads_last
+    H, P, S = cfg.ssm_heads(), cfg.ssm_head_dim, cfg.ssm_state
+    cache = jax.eval_shape(lambda: init_cache(cfg, 2, 16))
+    ssm = cache["mamba"]["ssm"] if cfg.family == "hybrid" else cache["ssm"]
+    assert ssm.shape == (cfg.num_layers, 2) + (
+        (P, S, H) if heads_last else (H, P, S))
+    assert ssm.shape[ssm_heads_axis(cfg)] == H
 
 
 def test_moe_routing_weights_sum():

@@ -80,6 +80,37 @@ def test_prefill_then_decode_matches_reference(seeded, bucket):
                                rtol=0, atol=TOL)
 
 
+def test_heads_last_state_serves_what_the_forward_gives():
+    """The smoke block with 16 heads of 8 and a state of 8, so the SSM
+    state is stored heads-last (the published Zamba2-7B's order): a
+    13-token prompt prefilled in a bucket of 16, then decoded through the
+    cache, gives every logit the full forward gives at that position.
+    These weights give logits up to about 57, where float32's spacing is
+    3.8e-6: both storage orders read 1.5e-5 off the forward, so the
+    tolerance is relative (1e-6) on top of ``TOL``."""
+    import dataclasses
+    from repro.models.ssm import ssm_heads_last
+    cfg = dataclasses.replace(CFG, ssm_head_dim=8, ssm_state=8)
+    assert ssm_heads_last(cfg) and cfg.ssm_heads() == 16
+    params = init_model(jax.random.PRNGKey(5), cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(6), (T,), 0, cfg.vocab_size)
+    ref, _ = jax.jit(lambda p, t: model_forward(p, cfg, t))(params,
+                                                           toks[None])
+    n = 13
+    pad = jnp.zeros((1, 16), jnp.int32).at[0, :n].set(toks[:n])
+    cache = init_cache(cfg, 1, 32, jnp.float32)
+    lg, st = jax.jit(lambda p, t, c: prefill(p, cfg, t, c, true_len=n))(
+        params, pad, cache)
+    got = [lg[0, -1]]
+    dec = jax.jit(lambda p, t, s: decode_step(p, cfg, t, s))
+    for i in range(n, T - 1):
+        lg, st = dec(params, toks[None, i:i + 1], st)
+        got.append(lg[0, -1])
+    np.testing.assert_allclose(np.asarray(jnp.stack(got)),
+                               np.asarray(ref[0, n - 1:T - 1]), rtol=1e-6,
+                               atol=TOL)
+
+
 @pytest.mark.parametrize("arch", ["zamba2-7b", "mamba2-780m"])
 def test_padded_prefill_leaves_the_state_of_the_exact_one(arch):
     """Past the true length ``dt`` and the SSM input are 0, so the SSM
